@@ -45,6 +45,8 @@ def main() -> None:
     if args.only and args.only not in {name for name, _ in SUITES}:
         ap.error(f"unknown suite {args.only!r}; choose from "
                  f"{', '.join(name for name, _ in SUITES)}")
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = []

@@ -42,14 +42,6 @@ python examples/quickstart.py --stanza 6 || {
   exit 1
 }
 
-# Test extras: hypothesis powers the property suites; without it those tests
-# skip (importorskip), so an offline container still runs tier-1 green.
-if ! python -c "import hypothesis" >/dev/null 2>&1; then
-  echo "[ci] installing test extras (hypothesis)"
-  python -m pip install --quiet hypothesis \
-    || echo "[ci] WARNING: hypothesis unavailable (offline?); property tests will skip"
-fi
-
 # Streaming vetting first and explicitly (-x): the streaming differential
 # suite locks every incremental tick to the batch oracle, and the simulator
 # determinism suite pins the ground truth every oracle is built from — if

@@ -102,8 +102,8 @@ class VetEngine:
     and ``cut_space`` ("log" framework default / "raw" paper-literal).
     ``backend`` picks the execution path, see ``repro.engine`` docstring;
     ``interpret`` picks the Pallas kernel mode — ``None`` (default) resolves
-    the platform policy (compiled on TPU, interpret elsewhere, overridable
-    via ``REPRO_PALLAS_INTERPRET`` — see ``repro.kernels.runtime``).
+    the platform policy (compiled on TPU, interpret elsewhere — see
+    ``repro.kernels.runtime``).
     ``fused`` routes windowed entry points (``vet_sliding``/``vet_windows``
     and the stream/mux tick paths) through the fused block-sparse Pallas
     kernel (``repro.kernels.windowvet``): one launch per ragged window set
@@ -177,6 +177,22 @@ class VetEngine:
         self.tracer = None
         self.trace_tid = 0
         self._seen_shapes: set = set()
+        # Placement: ``device_index`` k commits every dispatch's inputs to
+        # ``jax.devices()[k % device_count]`` (``ShardedVetMux`` sets it to
+        # the shard index); ``None`` leaves placement to JAX's default
+        # device.  ``result_device`` is the device the newest compiled
+        # dispatch's result array lived on.
+        self.device_index: Optional[int] = None
+        self.result_device = None
+
+    @property
+    def device(self):
+        """Device this engine commits its inputs to (``None`` = JAX's
+        default), resolved on first dispatch, never at construction."""
+        if self.device_index is None:
+            return None
+        devices = jax.devices()
+        return devices[self.device_index % len(devices)]
 
     def set_tracer(self, tracer, tid: int = 0) -> None:
         """Attach (or detach, with ``None``) a ``repro.obs.Tracer``; spans
@@ -200,7 +216,7 @@ class VetEngine:
     @property
     def interpret(self) -> bool:
         """Resolved Pallas kernel mode (``repro.kernels.runtime`` policy:
-        explicit argument > ``REPRO_PALLAS_INTERPRET`` > platform probe).
+        explicit argument, else the platform probe).
         The platform probe runs on first access, not at construction."""
         if self._interpret is None:
             self._interpret = resolve_interpret(None)
@@ -214,8 +230,8 @@ class VetEngine:
         model separate processes), and ``fleet.transport`` ships the same
         recipe across real process boundaries (``EngineSpec``).  The
         unresolved ``interpret`` argument is forwarded — not the resolved
-        bool — so a clone built in another process re-resolves its own
-        platform policy / environment override.
+        bool — so a clone built in another process resolves its own
+        platform.
         """
         return VetEngine(self.backend, omega=self.omega, buckets=self.buckets,
                          cut_space=self.cut_space,
@@ -425,7 +441,10 @@ class VetEngine:
                 return self._numpy_batch(m)
             if self._batch_fn is None:
                 self._batch_fn = self._make_batch_fn()
-            vet, ei, oc, pr, t = self._batch_fn(m)
+            dev = self.device
+            vet, ei, oc, pr, t = self._batch_fn(
+                m if dev is None else jax.device_put(m, dev))
+            self.result_device = next(iter(vet.devices()))
             # Host conversion stays in-span: jax dispatch is async, the
             # device sync happens here.
             w = m.shape[0]
@@ -454,8 +473,8 @@ class VetEngine:
         """One fused launch over ragged windows of a shared arena.
 
         The fused twin of ``_vet_batch_impl``: counts one dispatch, stages
-        O(arena + rows) bytes (the kernel slices windows out of the arena
-        in VMEM — no gather matrix is ever materialized)."""
+        O(arena + rows) bytes from the host (the windows are cut out of the
+        arena on the device — no host gather matrix is ever built)."""
         self.dispatches += 1
         max_len = int(lengths.max())
         nbytes = staged_bytes(arena.size, starts.size, max_len)
@@ -470,9 +489,10 @@ class VetEngine:
                        "fused", (1 << max(0, arena.size - 1).bit_length(),
                                  1 << max(0, starts.size - 1).bit_length(),
                                  1 << max(0, max_len - 1).bit_length()))):
-            vet, ei, oc, pr, t, n = fused_window_vet(
+            vet, ei, oc, pr, t, n, self.result_device = fused_window_vet(
                 arena, starts, lengths, omega=self.omega,
-                cut_space=self.cut_space, interpret=self.interpret)
+                cut_space=self.cut_space, interpret=self.interpret,
+                device=self.device)
             return BatchVetResult(vet=vet, ei=ei, oc=oc, pr=pr, t=t, n=n)
 
     def pad_rows_pow2(self, matrix: np.ndarray):

@@ -304,6 +304,8 @@ class ShardedVetMux:
             given (one shard per engine).
         engines: explicit per-shard engines (each shard models one
             process/host, so engines are never shared between shards).
+            Shard k's engine commits its inputs to ``jax.devices()[k %
+            device_count]`` (``VetEngine.device_index``).
         engine: a template engine; shard 0 uses it directly and shards
             1..K-1 get fresh engines with the same configuration.  Mutually
             exclusive with ``engines``.
@@ -373,6 +375,10 @@ class ShardedVetMux:
             if budget < 1:
                 raise ValueError(
                     f"budget must be >= 1 window row, got {budget}")
+        # Shard k dispatches on jax.devices()[k % device_count]: one chip
+        # per shard on a multi-chip host, all on the one device otherwise.
+        for k, e in enumerate(engines):
+            e.device_index = k
         self.budget = budget
         self._placer = ShardPlacer(len(engines), placement)
         self._muxes = [VetMux(e, tenant_weights=tenant_weights,

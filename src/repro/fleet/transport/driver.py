@@ -36,7 +36,9 @@ both to ``ShardedVetMux``) across the scenario bank.
 
 from __future__ import annotations
 
+import contextlib
 import multiprocessing as mp
+import os
 import time
 from typing import (
     Any,
@@ -54,7 +56,7 @@ from typing import (
 import numpy as np
 
 from ...engine import BatchVetResult, VetEngine, VetStream
-from ...kernels.runtime import platform_default_hint
+from ...kernels import runtime
 from ...obs.trace import span as _span, timed as _timed
 from ..mux import MuxStats, MuxTick, _flush_loop
 from ..schedule import split_budget
@@ -113,13 +115,32 @@ class _LocalChannel:
         pass
 
 
+@contextlib.contextmanager
+def _cpu_only_env():
+    """Pin processes started inside the block to ``JAX_PLATFORMS=cpu``.
+
+    A chip belongs to one process: the driver that has touched JAX holds
+    it, and a worker that reached for it would fail or hang.  A spawned
+    child inherits the environment as it stands at ``start()``."""
+    old = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        yield
+    finally:
+        if old is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = old
+
+
 class _ProcessChannel:
     """One shard worker process plus its duplex pipe.
 
     A transport failure tears the whole channel down (``kill``): the stale
     pipe is discarded with the dead process, so a late reply from a hung
     worker can never desynchronize a fresh command stream — every revive
-    starts a new process on a new pipe.
+    starts a new process on a new pipe.  Workers run on the CPU
+    (``_cpu_only_env``).
     """
 
     def __init__(self, ctx, spec: EngineSpec, tenant_weights: dict,
@@ -141,9 +162,10 @@ class _ProcessChannel:
         self._proc = self._ctx.Process(
             target=shard_worker_main,
             args=(child, self._spec, self._tenant_weights,
-                  self._urgent_headroom, platform_default_hint()),
+                  self._urgent_headroom),
             daemon=True)
-        self._proc.start()
+        with _cpu_only_env():
+            self._proc.start()
         child.close()
         self._conn = parent
 
@@ -413,7 +435,10 @@ class TransportVetMux:
             values trade checkpoint traffic for replaying more feeds —
             and re-vetting the un-checkpointed ticks' windows — on crash).
         mp_context: multiprocessing start method (default ``"spawn"``:
-            fork-safety with jax in play; see ``repro.kernels.runtime``).
+            fork-safety with jax in play, and the worker inherits the
+            ``JAX_PLATFORMS=cpu`` pin at start).  On a TPU host the process
+            driver takes only ``numpy`` engines (``ValueError`` otherwise):
+            ``ShardedVetMux`` is the on-chip path.
         sleep: backoff sleeper, injectable for tests.
         tracer: optional ``repro.obs.Tracer``.  When set, driver-side work
             traces onto pid 0 (``fleet.*`` on lane 0, ``transport.*`` on
@@ -498,6 +523,14 @@ class TransportVetMux:
         tw = dict(tenant_weights or {})
         uh = int(urgent_headroom)
         if driver == "process":
+            device = sorted({s.backend for s in specs} - {"numpy"})
+            if device and runtime.platform() == "tpu":
+                raise ValueError(
+                    f"process shard workers run on the CPU, but backend "
+                    f"{device[0]!r} needs this host's TPU, which this "
+                    f"process holds; use backend='numpy' here, or "
+                    f"ShardedVetMux(shards, backend={device[0]!r}) to run "
+                    f"the shards on the chips in one process")
             ctx = (mp.get_context(mp_context) if isinstance(mp_context, str)
                    else mp_context)
             channels = [_ProcessChannel(ctx, s, tw, uh) for s in specs]

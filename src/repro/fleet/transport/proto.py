@@ -70,11 +70,8 @@ class EngineSpec(NamedTuple):
     caches and dispatch counters are per-process artifacts — so the driver
     ships the configuration and each worker builds its own engine from it.
     ``interpret`` carries the *unresolved* argument (``None`` = platform
-    policy): the worker re-resolves it locally, seeded with the parent's
-    probed platform so it never runs backend discovery itself
-    (``repro.kernels.runtime.seed_platform_default``); exporting
-    ``REPRO_PALLAS_INTERPRET`` — inherited through the worker's environment
-    — overrides every worker at once.
+    policy): the worker resolves it locally, on the CPU it is pinned to
+    (see ``driver._ProcessChannel``).
     """
 
     backend: str

@@ -107,8 +107,7 @@ class ShardWorker:
         return self.mux.stats
 
 
-def shard_worker_main(conn, spec, tenant_weights, urgent_headroom,
-                      platform_hint) -> None:
+def shard_worker_main(conn, spec, tenant_weights, urgent_headroom) -> None:
     """Entry point of one shard worker process (the multiprocessing target).
 
     Blocks on the pipe for ``(op, payload)`` commands, executes them
@@ -116,18 +115,14 @@ def shard_worker_main(conn, spec, tenant_weights, urgent_headroom,
     ``("err", exc_type_name, message)``.  The loop exits on ``shutdown``
     or a closed pipe (driver gone).
 
-    ``platform_hint`` seeds ``repro.kernels.runtime`` with the parent's
-    already-probed Pallas platform policy, so the worker never runs jax
-    backend discovery itself (``REPRO_PALLAS_INTERPRET``, inherited via the
-    environment, still overrides).
+    The driver starts this process with ``JAX_PLATFORMS=cpu``: whatever
+    its engine resolves, it resolves on the CPU, never on the parent's chip.
 
     Fault injection (tests only): a ``fault`` command arms a
     ``WorkerFault``; at the armed tick the process ``os._exit``s —
     ``"before"`` loses the tick entirely, ``"mid"`` computes and commits it
     first but dies before replying (see ``proto.WorkerFault``).
     """
-    from ...kernels import runtime
-    runtime.seed_platform_default(platform_hint)
     worker = ShardWorker(spec.build(), tenant_weights=tenant_weights,
                          urgent_headroom=urgent_headroom)
     armed: Optional[WorkerFault] = None

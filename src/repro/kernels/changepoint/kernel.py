@@ -51,7 +51,9 @@ def _kernel(cy_ref, cyy_ref, cxy_ref, sx1_ref, sxx1_ref, sx2_ref, sxx2_ref,
             tot_ref, sse_ref, *, block: int, n: int, omega: int):
     pid = pl.program_id(0)
     base = (pid * block).astype(jnp.float32)
-    k = base + jax.lax.broadcasted_iota(jnp.float32, (block,), 0) + 1.0
+    # Mosaic's iota is integer-only: build it in int32 and cast (exact).
+    k = base + jax.lax.broadcasted_iota(jnp.int32, (block,), 0).astype(
+        jnp.float32) + 1.0
 
     cy = cy_ref[...]
     cyy = cyy_ref[...]
@@ -84,8 +86,8 @@ def sse_scan(cy, cyy, cxy, sx1, sxx1, sx2, sxx2, totals, *, true_n: int,
     (``core.changepoint.index_closed_forms``, rounded once to f32);
     totals: (3,) f32 = [sum y, sum y^2, sum x*y]; true_n: unpadded length.
     ``interpret=None`` resolves the platform policy (compiled on TPU,
-    interpret elsewhere; ``REPRO_PALLAS_INTERPRET`` overrides) at trace
-    time — pass an explicit bool to pin the mode.
+    interpret elsewhere) at trace time — pass an explicit bool to pin the
+    mode.
     Returns sse: (n_padded,) f32 (+inf outside the probing window / padding).
     """
     interpret = resolve_interpret(interpret)

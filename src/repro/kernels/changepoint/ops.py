@@ -79,7 +79,7 @@ def changepoint_pallas(y_sorted, omega: int = 3, block: int = DEFAULT_BLOCK,
     """t-hat (1-indexed prefix size), matching ``core.estimate_changepoint``.
 
     ``interpret=None`` picks the platform default (compiled on TPU,
-    interpret elsewhere; ``REPRO_PALLAS_INTERPRET`` overrides).
+    interpret elsewhere).
 
     Raises:
         ValueError: ``n < 2*omega`` — no valid split exists (the SSE scan

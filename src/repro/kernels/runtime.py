@@ -2,116 +2,55 @@
 
 Every kernel here runs in one of two modes:
 
-- **compiled** — ``pl.pallas_call(..., interpret=False)``: the real Mosaic
-  lowering.  Only meaningful on a TPU host.
+- **compiled** — ``pl.pallas_call(..., interpret=False)``: the Mosaic
+  lowering, which is what a TPU host runs.
 - **interpret** — the kernel body is evaluated op-by-op by XLA on the host.
   Bit-for-bit the semantics of the kernel jaxpr, so it doubles as the
-  *oracle* for the compiled path (the differential suites run it on CPU
-  containers).
+  *oracle* for the compiled path (the differential suites run it on CPU).
 
-Historically each kernel hardcoded ``interpret=True`` — correct on the CPU
-containers the tests run on, silently wrong on a real TPU (the kernel would
-interpret instead of compile and the "kernel" benchmark numbers would be
-the interpreter's).  ``resolve_interpret`` centralizes the default:
+The platform alone decides the default: TPU hosts compile, every other
+backend interprets.  An explicit ``interpret=`` argument (tests pin it)
+passes through untouched.  There is no environment override — a TPU host
+never falls back to the interpreter, so a kernel number taken there is the
+kernel's.
 
-1. an explicit ``interpret=`` argument always wins;
-2. else the ``REPRO_PALLAS_INTERPRET`` environment variable (``1/true/yes``
-   forces interpret mode, ``0/false/no`` forces compiled — the escape hatch
-   for debugging a miscompile on TPU or smoke-testing lowering on CPU).
-   Child processes inherit the parent's environment, so exporting it is
-   also the blanket *worker-side* override for the transport layer
-   (``repro.fleet.transport``) — every shard worker resolves the same mode
-   without any probe;
-3. else the platform: ``jax.default_backend()`` is probed once per process
-   — TPU hosts compile, everything else interprets.
-
-The platform probe is **lazy and fork-safe**: it runs on the first kernel
-dispatch that actually needs it, never at import or engine-construction
-time.  Backend discovery spins up threads (and on TPU touches the device
-runtime), so a probe baked into a constructor would fire inside every
-transport worker the moment it builds its engine — and a ``fork()``ed
-child re-running discovery mid-probe can deadlock TPU initialization.
-Workers instead inherit the parent's already-resolved policy via
-``seed_platform_default`` and never probe at all.
+The platform probe is **lazy**: it runs on the first kernel dispatch that
+needs it, never at import or engine-construction time.  Backend discovery
+spins up threads and, on a TPU host, claims the chip for this process.
+Child processes the repo starts (``repro.fleet.transport`` shard workers,
+``repro.launch.dryrun`` cells) are pinned to ``JAX_PLATFORMS=cpu`` in their
+own environment, so they probe the CPU and never contend for the parent's
+chip.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
 
-__all__ = [
-    "resolve_interpret",
-    "default_interpret",
-    "seed_platform_default",
-    "platform_default_hint",
-]
+__all__ = ["platform", "resolve_interpret"]
 
-_TRUTHY = ("1", "true", "yes", "on")
-_FALSY = ("0", "false", "no", "off")
-
-ENV_VAR = "REPRO_PALLAS_INTERPRET"
-
-# Memoized platform policy.  None = not probed yet; the probe is deferred
-# to the first resolve that needs it (module state instead of lru_cache so
-# a worker process can be seeded without triggering the probe — see
-# seed_platform_default).
-_PLATFORM: Optional[bool] = None
+# Memoized ``jax.default_backend()``.  None = not probed yet (module state,
+# so tests can stand in a platform without a device).
+_PLATFORM: Optional[str] = None
 
 
-def _platform_default() -> bool:
-    # Probed once per process: backend discovery is stable for its lifetime.
+def platform() -> str:
+    """This process's JAX backend (``"tpu"``, ``"cpu"``, ...), probed once
+    on first use: backend discovery is stable for a process's lifetime."""
     global _PLATFORM
     if _PLATFORM is None:
-        _PLATFORM = jax.default_backend() != "tpu"
+        _PLATFORM = jax.default_backend()
     return _PLATFORM
-
-
-def seed_platform_default(interpret: Optional[bool]) -> None:
-    """Install a pre-resolved platform policy without probing.
-
-    The transport driver calls this in every shard worker with the parent
-    process's already-memoized policy (``platform_default_hint()``), so
-    workers never run backend discovery themselves — the fork-safety half
-    of the lazy-probe contract.  ``None`` (parent never probed either)
-    leaves the lazy probe armed.  ``REPRO_PALLAS_INTERPRET`` still wins
-    over the seed: ``default_interpret`` checks the environment first.
-    """
-    global _PLATFORM
-    if interpret is not None:
-        _PLATFORM = bool(interpret)
-
-
-def platform_default_hint() -> Optional[bool]:
-    """This process's memoized platform policy, or ``None`` if it has never
-    been probed (nor seeded) — what a driver forwards to its workers."""
-    return _PLATFORM
-
-
-def default_interpret() -> bool:
-    """The resolved process-wide default (env override, else platform)."""
-    env = os.environ.get(ENV_VAR)
-    if env is not None:
-        val = env.strip().lower()
-        if val in _TRUTHY:
-            return True
-        if val in _FALSY:
-            return False
-        raise ValueError(
-            f"{ENV_VAR}={env!r} is not a boolean; use one of "
-            f"{_TRUTHY + _FALSY}")
-    return _platform_default()
 
 
 def resolve_interpret(interpret=None) -> bool:
     """Resolve an ``interpret=`` kernel argument to a concrete bool.
 
-    ``None`` (the kernel-op default) means "platform policy": compiled on
-    TPU, interpret elsewhere, overridable via ``REPRO_PALLAS_INTERPRET``.
-    An explicit bool passes through untouched.
+    ``None`` (the kernel-op default) means the platform default: compiled
+    on TPU, interpret elsewhere.  An explicit bool passes through untouched.
     """
     if interpret is None:
-        return default_interpret()
+        return platform() != "tpu"
     return bool(interpret)

@@ -6,21 +6,28 @@ window in a block-sparse row set: row ``r`` covers arena records
 ``[starts[r], starts[r] + lengths[r])``.  This retires the engine's
 one-dispatch-per-window-length rule — mixed-length window sets that the
 gather path had to bucket by shape become rows of one padded launch — and
-its O(windows x length) gather matrices: the kernel reads each window with a
-dynamic slice of the arena resident in VMEM, so staged memory is O(arena).
+its host-built O(windows x length) gather matrices: the host stages the
+arena once (O(arena + rows) bytes), and the launch's own jit cuts the
+``(rows, lmax)`` windows out of it on the device.
 
 Layout (the graphax ``BlockSparseTensor`` idiom: dense blocks + an index map
 describing where each block lives in the sparse whole):
 
+  jit    : windows = arena[starts[:, None] + iota(lmax)]   (HBM gather)
   grid   = (rows / BLOCK_ROWS,)
-  in     : arena (alen,) VMEM, replicated to every grid step
-           starts, lengths, pr, sq (BLOCK_ROWS,) per-step row metadata
+  in     : windows (BLOCK_ROWS, lmax) per grid step
+           lengths, pr (BLOCK_ROWS, 1) per-step row metadata
   out    : (BLOCK_ROWS, LANES) result lanes
            [vet, ei, oc, pr, t, n, 0, 0]
 
+Nothing is replicated into VMEM: a grid step holds its own rows only, so
+the launch fits any arena the device's HBM holds.  (Mosaic refuses the
+alternatives: an unaligned dynamic slice of a 1-D VMEM arena, and a
+per-row DMA out of a 1-D HBM arena, both for their tiling.)
+
 Per row the kernel fuses what used to be four dispatches worth of work:
 
-  slice -> bitonic sort -> prefix-sum SSE scan -> argmin cut -> capped
+  bitonic sort -> prefix-sum SSE scan -> argmin cut -> capped
   linear extrapolation -> EI/OC reduction
 
 Numerical contracts (the differential ladder leans on these):
@@ -44,7 +51,7 @@ Numerical contracts (the differential ladder leans on these):
   steps add shifted-in zeros — but its rounding differs from the reference
   by a few ulp, so compiled-vs-interpret near-tie flips carry the same
   documented caveat as ``kernels.changepoint``.
-- **Ring prefix sums.**  PR comes from f64 prefix sums over the arena,
+- **Ring prefix sums.**  PR comes from an f64 prefix sum over the arena,
   computed once on the host and handed in per row — overlapping windows
   share that work instead of re-reducing their rows, and a window's PR is
   exact to f32 rounding rather than carrying f32 accumulation error across
@@ -58,10 +65,10 @@ Numerical contracts (the differential ladder leans on these):
   conditioning agree here instead of trading off (see
   ``kernels.changepoint`` for the history of that trade).
 
-TPU caveat: per-row slice starts are read from the VMEM metadata block; a
-production TPU build would prefetch them to SMEM (PrefetchScalarGridSpec).
-The compiled path is best-effort on this CPU container — interpret mode is
-the tested oracle (see ``kernels.runtime``).
+The compiled path is compiled for a described v5e in the tests
+(``tests/test_tpu_compile.py``) and run on one by ``chip_smoke.py``, which
+holds it to the jax gather path and the ``core.vet_task`` oracle; interpret
+mode stays the oracle the CPU suites pin (see ``kernels.runtime``).
 """
 
 from __future__ import annotations
@@ -71,6 +78,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..runtime import resolve_interpret
 
 __all__ = ["fused_window_vet_scan", "BLOCK_ROWS", "LANES"]
 
@@ -115,10 +124,13 @@ def _bitonic_sort(x):
     while k <= width:
         j = k // 2
         while j >= 1:
-            partner = x.reshape(rows, -1, 2, j)[:, :, ::-1, :] \
-                .reshape(rows, width)
+            # Partner i ^ j: i + j in the lower half of each 2j-run, i - j
+            # in the upper half.  Two lane rolls and a select lower on
+            # Mosaic (a reversed-axis partner does not: no ``rev``).
+            lower = (iota & j) == 0
+            partner = jnp.where(lower, jnp.roll(x, -j, 1), jnp.roll(x, j, 1))
             ascending = (iota & k) == 0
-            keep_min = ascending == ((iota & j) == 0)
+            keep_min = ascending == lower
             x = jnp.where(keep_min, jnp.minimum(x, partner),
                           jnp.maximum(x, partner))
             j //= 2
@@ -145,15 +157,12 @@ def _pick(values, index):
     return jnp.sum(jnp.where(iota == index[:, None], values, 0.0), axis=1)
 
 
-def _kernel(arena_ref, starts_ref, lengths_ref, pr_ref, sq_ref, out_ref, *,
-            lmax: int, block_rows: int, omega: int, log_space: bool,
+def _kernel(win_ref, lengths_ref, pr_ref, out_ref, *, lmax: int,
+            block_rows: int, omega: int, log_space: bool,
             reference_rounding: bool):
-    # ---- block-sparse load: one dynamic arena slice per row --------------
-    rows = [arena_ref[pl.ds(starts_ref[j], lmax)] for j in range(block_rows)]
-    y = jnp.stack(rows)  # (B, lmax) f32
-    n = lengths_ref[...]  # (B,) int32
-    pr = pr_ref[...]  # (B,) f32: f64 ring prefix-sum window totals
-    del sq_ref  # totals of squares: unused since the centered scan landed
+    y = win_ref[...]  # (B, lmax) f32 raw windows, garbage past each length
+    n = lengths_ref[:, 0]  # (B,) int32
+    pr = pr_ref[:, 0]  # (B,) f32: f64 ring prefix-sum window totals
 
     iota = jax.lax.broadcasted_iota(jnp.int32, (block_rows, lmax), 1)
     mask = iota < n[:, None]
@@ -181,8 +190,8 @@ def _kernel(arena_ref, starts_ref, lengths_ref, pr_ref, sq_ref, out_ref, *,
 
     # Totals read from the centered scans at the row's last valid position —
     # the same values the reference's cumsum tail yields.  (The host's f64
-    # ring totals pr/sq can't serve the centered scan; pr still feeds the
-    # PR output lane below.)
+    # ring total pr can't serve the centered scan; it feeds the PR output
+    # lane below.)
     last = iota == n[:, None] - 1
     tot_y = jnp.sum(jnp.where(last, cy, 0.0), axis=1)[:, None]
     tot_yy = jnp.sum(jnp.where(last, cyy, 0.0), axis=1)[:, None]
@@ -223,35 +232,37 @@ def _kernel(arena_ref, starts_ref, lengths_ref, pr_ref, sq_ref, out_ref, *,
 @functools.partial(
     jax.jit,
     static_argnames=("lmax", "block_rows", "omega", "log_space", "interpret"))
-def fused_window_vet_scan(arena, starts, lengths, pr, sq, *, lmax: int,
+def fused_window_vet_scan(arena, starts, lengths, pr, *, lmax: int,
                           block_rows: int = BLOCK_ROWS, omega: int = 3,
-                          log_space: bool = True, interpret: bool = True):
+                          log_space: bool = True, interpret=None):
     """One fused launch over a padded block-sparse window set.
 
-    arena: (alen,) f32, alen pow2 and >= max(starts) + lmax (no slice clamp);
+    arena: (alen,) f32, alen >= max(starts) + lmax (no gather clamp);
     starts/lengths: (rows,) int32, rows a multiple of ``block_rows``;
-    pr/sq: (rows,) f32 window sums / sums of squares from the host's f64
-    arena prefix sums (``sq`` is kept for call-site stability; the centered
-    SSE scan derives its totals in-kernel); lmax: pow2 padded window width.
+    pr: (rows,) f32 window sums from the host's f64 arena prefix sums;
+    lmax: pow2 padded window width.  ``interpret=None`` resolves the
+    platform policy (``kernels.runtime``) at trace time.
     Returns (rows, LANES) f32: [vet, ei, oc, pr, t, n, 0, 0] per row.
     """
+    interpret = resolve_interpret(interpret)
     rows = starts.shape[0]
     assert rows % block_rows == 0, (rows, block_rows)
-    grid = (rows // block_rows,)
+    # The windows are cut out of the device-resident arena here, in the
+    # same program as the launch: one HBM gather, no host round trip.
+    windows = arena[starts[:, None]
+                    + jnp.arange(lmax, dtype=starts.dtype)[None, :]]
     kern = functools.partial(_kernel, lmax=lmax, block_rows=block_rows,
                              omega=omega, log_space=log_space,
                              reference_rounding=interpret)
     return pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(rows // block_rows,),
         in_specs=[
-            pl.BlockSpec(arena.shape, lambda i: (0,)),  # whole-arena VMEM
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
+            pl.BlockSpec((block_rows, lmax), lambda i: (i, 0)),
+            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
+            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         interpret=interpret,
-    )(arena, starts, lengths, pr, sq)
+    )(windows, lengths[:, None], pr[:, None])

@@ -1,11 +1,12 @@
 """Host wrapper: ragged (arena, windows) -> one fused kernel launch.
 
 ``fused_window_vet`` is the entry point ``repro.engine`` routes through: it
-computes the f64 ring prefix sums once (PR and the raw-space SSE totals for
-*every* window come from two O(arena) cumsums — overlapping windows share
-the work), pads the row set and the arena to launch-stable pow2 shapes, and
-hands the kernel the block-sparse row map.  Staged bytes are O(arena + rows)
-— never the O(windows x length) gather matrix of the materialized path.
+computes the f64 ring prefix sums once (PR for *every* window comes from
+one O(arena) cumsum — overlapping windows share the work), pads the row set
+and the arena to launch-stable pow2 shapes, and hands the launch the
+block-sparse row map.  Host-to-device bytes are O(arena + rows) — never the
+O(windows x length) gather matrix of the materialized path; the windows are
+cut out of the arena on the device.
 
 Padding contract:
 
@@ -17,18 +18,33 @@ Padding contract:
   fleet's (rows are masked past their own length, and the scans are
   padding-invariant — see kernel.py);
 - the arena pads to a pow2 at least ``arena + lmax`` so every row's
-  ``pl.ds(start, lmax)`` slice stays in bounds (XLA clamps out-of-range
-  dynamic slices — padding keeps clamping from ever triggering).
+  ``lmax``-wide device gather stays in bounds (out-of-range gather indices
+  would clamp — padding keeps clamping from ever triggering).
 """
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
+import jax
 import numpy as np
 
 from ..runtime import resolve_interpret
 from .kernel import BLOCK_ROWS, fused_window_vet_scan
 
-__all__ = ["fused_window_vet", "staged_bytes"]
+__all__ = ["FusedVet", "fused_window_vet", "staged_bytes"]
+
+
+class FusedVet(NamedTuple):
+    """One fused launch's rows (host arrays) and the device it ran on."""
+
+    vet: np.ndarray
+    ei: np.ndarray
+    oc: np.ndarray
+    pr: np.ndarray
+    t: np.ndarray
+    n: np.ndarray
+    device: object  # jax.Device holding the launch's result array
 
 
 def _pow2(n: int) -> int:
@@ -36,18 +52,19 @@ def _pow2(n: int) -> int:
 
 
 def staged_bytes(arena_len: int, rows: int, max_len: int) -> int:
-    """Bytes one fused launch stages for the device: the padded f32 arena
-    plus four per-row metadata vectors (starts/lengths/pr/sq).  O(arena +
-    rows) — the number the benchmarks compare against the gather path's
-    O(windows x length) matrix."""
+    """Bytes one fused launch stages from host to device: the padded f32
+    arena plus three per-row metadata vectors (starts/lengths/pr).
+    O(arena + rows) — the number the benchmarks compare against the gather
+    path's O(windows x length) matrix.  (The kernel's (rows, lmax) window
+    block is cut out of the arena on the device: HBM traffic, not staging.)"""
     lmax = max(8, _pow2(int(max_len)))
     rows_p = max(BLOCK_ROWS, _pow2(int(rows)))
-    return 4 * _pow2(int(arena_len) + lmax) + 4 * 4 * rows_p
+    return 4 * _pow2(int(arena_len) + lmax) + 3 * 4 * rows_p
 
 
 def fused_window_vet(arena, starts, lengths, *, omega: int = 3,
                      cut_space: str = "log", interpret=None,
-                     block_rows: int = BLOCK_ROWS):
+                     block_rows: int = BLOCK_ROWS, device=None):
     """Vet every window ``arena[starts[r] : starts[r] + lengths[r])`` fused.
 
     Args:
@@ -60,9 +77,12 @@ def fused_window_vet(arena, starts, lengths, *, omega: int = 3,
         interpret: Pallas mode; ``None`` resolves the platform policy
             (``kernels.runtime.resolve_interpret``).
         block_rows: kernel rows per grid step.
+        device: ``jax.Device`` to commit the launch inputs to (``None`` =
+            JAX's default device).
 
     Returns:
-        ``(vet, ei, oc, pr, t, n)`` host arrays, one entry per input row.
+        ``FusedVet``: ``(vet, ei, oc, pr, t, n)`` host arrays, one entry
+        per input row, and the device the launch ran on.
     """
     a64 = np.asarray(arena, dtype=np.float64).ravel()
     starts = np.asarray(starts, dtype=np.int64).ravel()
@@ -78,12 +98,10 @@ def fused_window_vet(arena, starts, lengths, *, omega: int = 3,
     if starts.min() < 0 or (starts + lengths).max() > a64.size:
         raise ValueError("window out of arena bounds")
 
-    # Ring prefix sums (and of squares), one f64 pass over the arena: every
-    # window's PR / sum-of-squares is a difference of two entries.
+    # Ring prefix sums, one f64 pass over the arena: every window's PR is a
+    # difference of two entries.
     ps = np.concatenate([[0.0], np.cumsum(a64)])
-    ps2 = np.concatenate([[0.0], np.cumsum(a64 * a64)])
     pr64 = ps[starts + lengths] - ps[starts]
-    sq64 = ps2[starts + lengths] - ps2[starts]
 
     lmax = max(8, _pow2(int(lengths.max())))
     rows_p = max(block_rows, _pow2(rows))
@@ -92,30 +110,30 @@ def fused_window_vet(arena, starts, lengths, *, omega: int = 3,
         starts_p = np.concatenate([starts, np.repeat(starts[-1:], pad)])
         lengths_p = np.concatenate([lengths, np.repeat(lengths[-1:], pad)])
         pr_p = np.concatenate([pr64, np.repeat(pr64[-1:], pad)])
-        sq_p = np.concatenate([sq64, np.repeat(sq64[-1:], pad)])
     else:
-        starts_p, lengths_p, pr_p, sq_p = starts, lengths, pr64, sq64
+        starts_p, lengths_p, pr_p = starts, lengths, pr64
 
     alen = _pow2(a64.size + lmax)
     arena_f32 = np.zeros(alen, dtype=np.float32)
     arena_f32[:a64.size] = a64
 
+    inputs = (arena_f32, starts_p.astype(np.int32),
+              lengths_p.astype(np.int32), pr_p.astype(np.float32))
+    if device is not None:
+        inputs = jax.device_put(inputs, device)
     out = fused_window_vet_scan(
-        arena_f32,
-        starts_p.astype(np.int32),
-        lengths_p.astype(np.int32),
-        pr_p.astype(np.float32),
-        sq_p.astype(np.float32),
+        *inputs,
         lmax=lmax,
         block_rows=block_rows,
         omega=omega,
         log_space=(cut_space == "log"),
         interpret=resolve_interpret(interpret),
     )
+    ran_on = next(iter(out.devices()))
     out = np.asarray(out)[:rows]
     ei = out[:, 1].astype(np.float64)
     oc = out[:, 2].astype(np.float64)
     # PR (and vet's numerator) from the f64 ring prefix sums — exact to f32
     # rounding, matching the scalar oracle's sum to well under 1e-5.
-    return (pr64 / ei, ei, oc, pr64, out[:, 4].astype(np.int32),
-            lengths.astype(np.int64))
+    return FusedVet(pr64 / ei, ei, oc, pr64, out[:, 4].astype(np.int32),
+                    lengths.astype(np.int64), ran_on)

@@ -287,7 +287,9 @@ def sweep(out_path: str, meshes, only_arch=None, only_shape=None, timeout=3600):
         try:
             proc = subprocess.run(
                 cmd, capture_output=True, text=True, timeout=timeout,
-                env={**os.environ, "PYTHONPATH": "src"},
+                # Cells compile for a described mesh on host devices: the
+                # child never takes the chip from its parent.
+                env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"},
             )
             if proc.returncode == 0:
                 payload = json.loads(proc.stdout.strip().splitlines()[-1])

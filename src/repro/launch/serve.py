@@ -35,6 +35,7 @@ from ..models import decode_step, init_cache, init_params, prefill
 from ..obs import LedgerReport, Tracer, format_ledger, ledger_from, write_chrome
 from ..obs.trace import timed as _timed
 from ..profiling import RecordProfiler
+from .cache import enable_compile_cache
 
 __all__ = ["ServeResult", "serve"]
 
@@ -277,6 +278,7 @@ def main():
                          "here (Perfetto-loadable); also prints the "
                          "optimality ledger")
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -33,6 +33,7 @@ from ..models import init_params
 from ..optim.adamw import AdamWConfig, init_opt_state
 from ..profiling import PhaseTimer, RecordProfiler
 from ..sched.straggler import VetController
+from .cache import enable_compile_cache
 from .steps import make_train_step
 
 __all__ = ["TrainResult", "train"]
@@ -184,6 +185,7 @@ def main():
                     help="use the smoke-scale config (CPU-friendly)")
     ap.add_argument("--n-micro", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -353,7 +353,6 @@ def moe_apply(p, x, cfg, ctx: ShardCtx):
         y, aux = run(x, p, None, tokens)
     else:
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
 
         dp = ctx.dp_axes if len(ctx.dp_axes) > 1 else ctx.dp_axes[0]
         dp_size = 1
@@ -368,12 +367,12 @@ def moe_apply(p, x, cfg, ctx: ShardCtx):
             "wd": P(ctx.tp_axis, None, None),
         }
         routed = {k: p[k] for k in ("router", "wg", "wu", "wd")}
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             lambda xl, pl: run(xl, pl, ctx.tp_axis, t_local, tuple(ctx.dp_axes)),
             mesh=ctx.mesh,
             in_specs=(x_spec, p_spec),
             out_specs=(x_spec, P()),
-            check_rep=False,
+            check_vma=False,
         )(x, routed)
 
     if cfg.n_shared_experts:

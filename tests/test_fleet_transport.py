@@ -22,11 +22,15 @@ Three rungs of the differential ladder live here:
 Also locked here: retry/backoff semantics against a fault-injecting fake
 channel (exact exponential schedule, retry-budget exhaustion, logical
 errors never retried), checkpoint/resume state roundtrips at the mux and
-stream level, the fork-safe lazy platform probe (engine construction never
-triggers backend discovery; workers inherit the parent's policy), and the
+stream level, the lazy platform probe (engine construction never triggers
+backend discovery; workers start pinned to the CPU, and a TPU host's process
+driver refuses device engines), and the
 transport surface's loud deltas (attached streams rejected, ``stream()``
 redirects to ``collect``).
 """
+
+import multiprocessing as mp
+import os
 
 import numpy as np
 import pytest
@@ -396,12 +400,43 @@ class TestCheckpointRoundtrip:
             assert "a" not in fleet
 
 
-# ------------------------------------------------- fork-safe lazy probe
+# ------------------------------------------------------ platform policy
+class _RecordingContext:
+    """multiprocessing-context stand-in: records the ``JAX_PLATFORMS`` each
+    worker process would start with, and starts nothing."""
+
+    def __init__(self):
+        self.started_with = []
+
+    def Pipe(self, duplex=True):
+        return mp.Pipe(duplex)
+
+    def Process(self, target, args, daemon):
+        return _RecordingProcess(self)
+
+
+class _RecordingProcess:
+    def __init__(self, ctx):
+        self._ctx = ctx
+
+    def start(self):
+        self._ctx.started_with.append(os.environ.get("JAX_PLATFORMS"))
+
+    def is_alive(self):
+        return False
+
+    def terminate(self):
+        pass
+
+    def join(self, timeout=None):
+        pass
+
+
 class TestRuntimePolicy:
     def test_engine_construction_never_probes_the_backend(self, monkeypatch):
         """Building an engine (as every spawning worker does) must not
-        trigger jax backend discovery — the probe deadlock-bait the lazy
-        policy exists to avoid."""
+        trigger jax backend discovery, which on a TPU host claims the
+        chip."""
         monkeypatch.setattr(runtime, "_PLATFORM", None)
         def boom():
             raise AssertionError("backend discovery ran at construction")
@@ -413,31 +448,47 @@ class TestRuntimePolicy:
 
     def test_interpret_resolves_lazily_on_first_access(self, monkeypatch):
         monkeypatch.setattr(runtime, "_PLATFORM", None)
-        monkeypatch.delenv(runtime.ENV_VAR, raising=False)
         monkeypatch.setattr(runtime.jax, "default_backend", lambda: "cpu")
         eng = VetEngine("numpy", buckets=64)
         assert eng.interpret is True  # cpu probes to interpret mode
-        assert runtime.platform_default_hint() is True  # memoized
+        assert runtime._PLATFORM == "cpu"  # memoized
 
-    def test_seed_installs_the_parent_policy_without_probing(self, monkeypatch):
+    @pytest.mark.parametrize("platform,interpret",
+                             [("tpu", False), ("cpu", True)])
+    def test_platform_alone_decides_the_mode(self, monkeypatch, platform,
+                                             interpret):
+        monkeypatch.setattr(runtime, "_PLATFORM", platform)
+        assert runtime.resolve_interpret(None) is interpret
+        assert VetEngine("pallas").interpret is interpret
+
+    def test_explicit_interpret_overrides_the_platform(self, monkeypatch):
+        monkeypatch.setattr(runtime, "_PLATFORM", "tpu")
+        assert runtime.resolve_interpret(True) is True
+        assert VetEngine("pallas", interpret=True).interpret is True
+
+    @pytest.mark.parametrize("backend", ["jax", "pallas"])
+    def test_process_driver_refuses_device_engines_on_a_tpu_host(
+            self, monkeypatch, backend):
+        monkeypatch.setattr(runtime, "_PLATFORM", "tpu")
+        ctx = _RecordingContext()
+        with pytest.raises(ValueError, match="ShardedVetMux"):
+            TransportVetMux(2, backend=backend, mp_context=ctx)
+        assert ctx.started_with == []  # refused before any worker started
+
+    def test_numpy_fleet_never_probes_the_platform(self, monkeypatch):
         monkeypatch.setattr(runtime, "_PLATFORM", None)
         def boom():
-            raise AssertionError("seeded worker must not probe")
+            raise AssertionError("a numpy fleet probed the backend")
         monkeypatch.setattr(runtime.jax, "default_backend", boom)
-        runtime.seed_platform_default(False)  # parent probed: TPU/compiled
-        assert runtime.platform_default_hint() is False
-        assert runtime.resolve_interpret(None) is False
+        TransportVetMux(2, backend="numpy",
+                        mp_context=_RecordingContext()).close()
 
-    def test_env_override_beats_the_seed(self, monkeypatch):
-        monkeypatch.setattr(runtime, "_PLATFORM", None)
-        runtime.seed_platform_default(False)
-        monkeypatch.setenv(runtime.ENV_VAR, "1")
-        assert runtime.resolve_interpret(None) is True
-
-    def test_seed_none_leaves_the_lazy_probe_armed(self, monkeypatch):
-        monkeypatch.setattr(runtime, "_PLATFORM", None)
-        runtime.seed_platform_default(None)
-        assert runtime.platform_default_hint() is None
+    def test_workers_start_pinned_to_the_cpu(self, monkeypatch):
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        ctx = _RecordingContext()
+        TransportVetMux(2, backend="numpy", mp_context=ctx).close()
+        assert ctx.started_with == ["cpu", "cpu"]
+        assert os.environ["JAX_PLATFORMS"] == "tpu"  # parent untouched
 
     def test_clone_forwards_the_unresolved_interpret_argument(self):
         explicit = VetEngine("numpy", buckets=64, interpret=True)

@@ -49,7 +49,7 @@ def arenas_with_windows(draw):
 @given(arenas_with_windows())
 def test_prop_fused_matches_gather_path_bitwise_t(case):
     arena, starts, lengths = case
-    vet, ei, oc, pr, t, n = fused_window_vet(arena, starts, lengths)
+    vet, ei, oc, pr, t, n, _ = fused_window_vet(arena, starts, lengths)
     gather = VetEngine("pallas", cache_size=0, fused=False)
     slices = list(zip(starts.tolist(), (starts + lengths).tolist()))
     g = gather.vet_windows(arena, slices)
@@ -65,7 +65,7 @@ def test_prop_fused_matches_gather_path_bitwise_t(case):
 @given(arenas_with_windows())
 def test_prop_fused_tracks_scalar_oracle_and_conserves(case):
     arena, starts, lengths = case
-    vet, ei, oc, pr, t, n = fused_window_vet(arena, starts, lengths)
+    vet, ei, oc, pr, t, n, _ = fused_window_vet(arena, starts, lengths)
     want = ref_window_vet(arena, starts, lengths)
     np.testing.assert_allclose(vet, want[0], rtol=2e-2, atol=1e-3)
     np.testing.assert_allclose(ei, want[1], rtol=2e-2, atol=1e-3)
