@@ -492,7 +492,7 @@ class VetEngine:
             vet, ei, oc, pr, t, n, self.result_device = fused_window_vet(
                 arena, starts, lengths, omega=self.omega,
                 cut_space=self.cut_space, interpret=self.interpret,
-                device=self.device)
+                device=self.device, tracer=self.tracer, tid=self.trace_tid)
             return BatchVetResult(vet=vet, ei=ei, oc=oc, pr=pr, t=t, n=n)
 
     def pad_rows_pow2(self, matrix: np.ndarray):

@@ -64,7 +64,6 @@ from typing import NamedTuple, Optional
 
 import numpy as np
 
-from ..obs.trace import span as _span
 from .engine import BatchVetResult, VetEngine, default_engine
 
 __all__ = ["RingDelta", "StreamDelta", "StreamStats", "VetStream"]
@@ -418,9 +417,7 @@ class VetStream:
         # of the same stream to hit the engine cache.
         key = ("stream", self.window, self.stride, self._vetted,
                self._vetted + n_new, self._epoch, self._fp.hexdigest())
-        with _span(self.engine.tracer, "stream.drain",
-                   tid=self.engine.trace_tid, windows=n_new):
-            matrix = self._gather(starts)
+        matrix = self._gather(starts)
         return StreamDelta(start=self._vetted, count=n_new,
                            matrix=matrix, key=key, epoch=self._epoch)
 
@@ -461,9 +458,7 @@ class VetStream:
                 f"are resident; tick() more often or raise capacity "
                 f"({self.capacity})")
         end = (self._vetted + n_new - 1) * self.stride + self.window
-        with _span(self.engine.tracer, "stream.drain",
-                   tid=self.engine.trace_tid, windows=n_new, ring=True):
-            arena = self._ring[np.arange(base, end) % self.capacity]
+        arena = self._ring[np.arange(base, end) % self.capacity]
         starts = np.arange(n_new, dtype=np.int64) * self.stride
         key = ("fusedring", self.window, self.stride, self._vetted,
                self._vetted + n_new, self._epoch, self._fp.hexdigest())
@@ -518,16 +513,14 @@ class VetStream:
                 f"result rows")
         self._reused_rows += self._vetted
         self._vetted_rows += delta.count
-        with _span(self.engine.tracer, "stream.commit",
-                   tid=self.engine.trace_tid, windows=delta.count):
-            self._splice(delta.start, rows)
-            self._vetted = delta.start + delta.count
-            if (self.history is not None
-                    and self._vetted - self._row_base > self.history):
-                evict_to = self._vetted - self.history
-                self._evicted_rows += evict_to - self._row_base
-                self._row_base = evict_to
-            self._last = None
+        self._splice(delta.start, rows)
+        self._vetted = delta.start + delta.count
+        if (self.history is not None
+                and self._vetted - self._row_base > self.history):
+            evict_to = self._vetted - self.history
+            self._evicted_rows += evict_to - self._row_base
+            self._row_base = evict_to
+        self._last = None
 
     def collect(self) -> Optional[BatchVetResult]:
         """Result over the retained vetted windows (frozen views), or ``None``
@@ -540,18 +533,16 @@ class VetStream:
             return None
         if self._last is not None:
             return self._last
-        with _span(self.engine.tracer, "stream.collect",
-                   tid=self.engine.trace_tid, windows=n_rows):
-            lo = self._row_base - self._phys_base
-            fields = {}
-            for name in ("vet", "ei", "oc", "pr", "t", "n"):
-                v = self._rows[name][lo:lo + n_rows]
-                v.flags.writeable = False  # restricts the view, not the base
-                fields[name] = v
-            res = BatchVetResult(**fields)
-            self._exposed = max(self._exposed, self._vetted)
-            self._last = res
-            return res
+        lo = self._row_base - self._phys_base
+        fields = {}
+        for name in ("vet", "ei", "oc", "pr", "t", "n"):
+            v = self._rows[name][lo:lo + n_rows]
+            v.flags.writeable = False  # restricts the view, not the base
+            fields[name] = v
+        res = BatchVetResult(**fields)
+        self._exposed = max(self._exposed, self._vetted)
+        self._last = res
+        return res
 
     def tick(self) -> Optional[BatchVetResult]:
         """Vet the windows that became complete since the last tick.

@@ -64,9 +64,13 @@ window.  Three defenses, all cheap:
 
 from __future__ import annotations
 
-from typing import Dict, Hashable, List, NamedTuple, Optional, Tuple
+from bisect import bisect_right
+from collections import deque
+from typing import Deque, Dict, Hashable, List, NamedTuple, Optional, Tuple
 
 import numpy as np
+
+from ..obs.trace import span as _span
 
 __all__ = ["AnomalyMonitor", "RegimeShift"]
 
@@ -142,17 +146,24 @@ def _single_segment_sse_f64(y: np.ndarray) -> float:
 
 
 class _StreamState:
-    """Per-stream ring + watermark + flags already raised."""
+    """Per-stream ring + watermark + flags already raised, and the
+    watermarks of the newest scans (enough to tell whether a window that
+    leaves the ring was scanned ``confirm`` times)."""
 
-    __slots__ = ("ring", "base", "seen", "onsets", "candidate", "hits")
+    __slots__ = ("ring", "base", "seen", "onsets", "candidate", "hits",
+                 "marks", "settled")
 
-    def __init__(self):
+    def __init__(self, confirm: int):
         self.ring: List[float] = []  # newest window vets, oldest first
         self.base = 0  # absolute window index of ring[0]
         self.seen = 0  # vetted-window watermark already consumed
         self.onsets: List[int] = []  # onsets already flagged
         self.candidate: Optional[int] = None  # onset awaiting confirmation
         self.hits = 0  # consecutive scans agreeing on the candidate
+        # Watermarks of the newest scans; window j is in the scans whose
+        # watermark lies in (j, j + ring].
+        self.marks: Deque[int] = deque(maxlen=confirm + 1)
+        self.settled = 0  # windows below this have had their last scan
 
     def reset(self, base: int = 0, seen: int = 0) -> None:
         self.ring.clear()
@@ -160,6 +171,8 @@ class _StreamState:
         self.onsets.clear()
         self.candidate = None
         self.hits = 0
+        self.marks.clear()
+        self.settled = base
 
 
 class AnomalyMonitor:
@@ -191,6 +204,18 @@ class AnomalyMonitor:
     Each onset is flagged once: re-detections within ``omega`` ticks of an
     already-raised onset are suppressed, while a genuinely new shift on the
     same stream (e.g. the restart edge after a failure) flags again.
+
+    Observability: with a ``repro.obs.Tracer`` attached (``set_tracer``;
+    ``VetMux.set_tracer`` leaves the monitor alone, since these are three
+    spans a scanned stream a tick), every scan is one ``anomaly.scan``
+    span whose own time is the
+    host's work (ring update, log vets, levels, the f64 SSE landscape, the
+    gates), with two children: ``anomaly.launch``, the call into the
+    argmin backend (on jax and pallas it returns before the device is
+    done), and ``anomaly.wait``, bringing the argmin to the host.
+    ``underscanned`` counts windows whose last scan has been made after
+    fewer than ``confirm`` scans: a shift there could not be confirmed,
+    which no latency shows.
     """
 
     def __init__(self, method: str = "numpy", *, ring: int = 64,
@@ -212,6 +237,9 @@ class AnomalyMonitor:
         self.confirm = max(int(confirm), 1)
         self._streams: Dict[Hashable, _StreamState] = {}
         self._raised = 0
+        self._underscanned = 0
+        self.tracer = None
+        self.trace_tid = 0
 
     def __repr__(self) -> str:
         return (f"AnomalyMonitor(method={self.method!r}, ring={self.ring}, "
@@ -221,6 +249,19 @@ class AnomalyMonitor:
     def raised(self) -> int:
         """Lifetime count of flags raised (``MuxStats.anomalies``)."""
         return self._raised
+
+    @property
+    def underscanned(self) -> int:
+        """Lifetime count of windows whose last scan has been made (the
+        next window evicts them from the ring) after fewer than
+        ``confirm`` scans."""
+        return self._underscanned
+
+    def set_tracer(self, tracer, tid: int = 0) -> None:
+        """Attach (or detach, with ``None``) a ``repro.obs.Tracer``; spans
+        land on lane ``tid``."""
+        self.tracer = tracer
+        self.trace_tid = int(tid)
 
     # ------------------------------------------------------------ observe
     def observe(self, stream_id: Hashable, vets, *, first: int,
@@ -244,7 +285,9 @@ class AnomalyMonitor:
         v = np.asarray(vets, np.float64).ravel()
         if v.size == 0:
             return ()
-        st = self._streams.setdefault(stream_id, _StreamState())
+        st = self._streams.get(stream_id)
+        if st is None:
+            st = self._streams[stream_id] = _StreamState(self.confirm)
         vetted = first + v.size  # stream's vetted-window watermark
         if vetted < st.seen or first > st.seen:
             # Rewind (stream reset / checkpoint restore) or a gap (windows
@@ -255,24 +298,48 @@ class AnomalyMonitor:
             # No fresh windows: rescanning the same ring would let a noise
             # cut "confirm" itself without new evidence.
             return ()
-        st.ring.extend(float(x) for x in new)
-        st.seen = vetted
-        drop = len(st.ring) - self.ring
-        if drop > 0:
-            del st.ring[:drop]
-            st.base += drop
-        return self._scan(stream_id, tenant, st)
+        # One span a scan: a ring still short of ``min_points`` after this
+        # observation is bookkeeping only.
+        scans = min(len(st.ring) + new.size, self.ring) >= self.min_points
+        with _span(self.tracer if scans else None, "anomaly.scan",
+                   tid=self.trace_tid):
+            st.ring.extend(float(x) for x in new)
+            st.seen = vetted
+            drop = len(st.ring) - self.ring
+            if drop > 0:
+                del st.ring[:drop]
+                st.base += drop
+            return self._scan(stream_id, tenant, st)
+
+    def _settle(self, st: _StreamState) -> None:
+        """Record a scan at watermark ``st.seen`` and count the windows
+        it is the last scan of (those at or below ``seen - ring``) that
+        got fewer than ``confirm`` scans in all."""
+        marks = st.marks
+        marks.append(st.seen)
+        last = st.seen - self.ring
+        for j in range(st.settled, last + 1):
+            # Every earlier mark is <= j + ring (j was not yet settled at
+            # the earlier scans); this scan's mark is only for j == last.
+            scans = len(marks) - bisect_right(marks, j) - (j < last)
+            if scans < self.confirm:
+                self._underscanned += 1
+        st.settled = max(st.settled, last + 1)
 
     def _scan(self, stream_id: Hashable, tenant: str,
               st: _StreamState) -> Tuple[RegimeShift, ...]:
         m = len(st.ring)
         if m < self.min_points:
             return ()
+        self._settle(st)
         # Log vets: a regime shift multiplies the overhead channel, so it
         # is additive here, and a single Pareto-tail spike no longer
         # dominates the SSE.  Levels are reported back as geometric means.
         z = np.log(np.maximum(np.asarray(st.ring, np.float64), _TINY))
-        t = self._argmin(z)  # 1-indexed prefix length within the ring
+        with _span(self.tracer, "anomaly.launch", tid=self.trace_tid):
+            t = self._launch(z)
+        with _span(self.tracer, "anomaly.wait", tid=self.trace_tid):
+            t = int(t)  # 1-indexed prefix length within the ring
         pre = float(np.exp(z[:t].mean()))
         post = float(np.exp(z[t:].mean()))
         sse0 = _single_segment_sse_f64(z)
@@ -299,17 +366,19 @@ class AnomalyMonitor:
         return (RegimeShift(stream_id=stream_id, tenant=tenant, onset=onset,
                             pre=pre, post=post, confidence=confidence),)
 
-    def _argmin(self, y: np.ndarray) -> int:
+    def _launch(self, y: np.ndarray):
+        """The backend's argmin (1-indexed prefix length), not yet on the
+        host: a numpy scalar, or a jax array the device may still be
+        computing."""
         if self.method == "numpy":
-            return int(np.argmin(_closed_form_scan_f64(y, self.omega))) + 1
+            return np.argmin(_closed_form_scan_f64(y, self.omega)) + 1
         if self.method == "jax":
             from ..core.changepoint import estimate_changepoint
-            return int(estimate_changepoint(
-                np.asarray(y, np.float32), omega=self.omega))
+            return estimate_changepoint(np.asarray(y, np.float32),
+                                        omega=self.omega)
         from ..kernels.changepoint.ops import auto_block, changepoint_pallas
-        return int(changepoint_pallas(np.asarray(y, np.float32),
-                                      omega=self.omega,
-                                      block=auto_block(y.size)))
+        return changepoint_pallas(np.asarray(y, np.float32),
+                                  omega=self.omega, block=auto_block(y.size))
 
     # ------------------------------------------------------------- churn
     def forget(self, stream_id: Hashable) -> None:
@@ -322,10 +391,12 @@ class AnomalyMonitor:
         return {
             "method": self.method,
             "raised": self._raised,
+            "underscanned": self._underscanned,
             "streams": [
                 {"sid": sid, "ring": list(st.ring), "base": st.base,
                  "seen": st.seen, "onsets": list(st.onsets),
-                 "candidate": st.candidate, "hits": st.hits}
+                 "candidate": st.candidate, "hits": st.hits,
+                 "marks": list(st.marks), "settled": st.settled}
                 for sid, st in self._streams.items()
             ],
         }
@@ -335,9 +406,10 @@ class AnomalyMonitor:
         shifts the snapshot already raised (the transport crash-recovery
         invariant, same as the mux's committed-window watermark)."""
         self._raised = int(state["raised"])
+        self._underscanned = int(state.get("underscanned", 0))
         self._streams = {}
         for rec in state["streams"]:
-            st = _StreamState()
+            st = _StreamState(self.confirm)
             st.ring = [float(x) for x in rec["ring"]]
             st.base = int(rec["base"])
             st.seen = int(rec["seen"])
@@ -345,6 +417,9 @@ class AnomalyMonitor:
             cand = rec.get("candidate")
             st.candidate = None if cand is None else int(cand)
             st.hits = int(rec.get("hits", 0))
+            st.marks.extend(int(w) for w in rec.get("marks", ()))
+            st.settled = int(rec.get(
+                "settled", max(st.base, st.seen - self.ring + 1)))
             self._streams[rec["sid"]] = st
 
 

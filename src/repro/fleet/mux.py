@@ -56,7 +56,9 @@ class MuxStats(NamedTuple):
     (``repro.fleet.transport``): an in-process mux never retries or
     respawns anything, so they default to 0 and only the cross-process
     driver reports non-zero values.  ``anomalies`` counts regime-shift
-    flags raised by the anomaly monitor (0 when monitoring is off).
+    flags raised by the anomaly monitor (0 when monitoring is off);
+    ``pressure_ticks`` the whole-mux ticks ``feed`` took because a
+    stream's ring was full (each also counts in ``ticks``).
     """
 
     ticks: int  # mux ticks
@@ -68,6 +70,7 @@ class MuxStats(NamedTuple):
     retries: int = 0  # transport round trips re-attempted after a failure
     respawns: int = 0  # shard worker processes restarted after a crash
     anomalies: int = 0  # regime-shift flags raised (repro.fleet.anomaly)
+    pressure_ticks: int = 0  # ticks ``feed`` took under ring pressure
 
 
 def _flush_loop(tick_fn, max_ticks: int):
@@ -195,6 +198,7 @@ class VetMux:
         self._rows = 0
         self._padded_rows = 0
         self._deferred = 0
+        self._pressure_ticks = 0
 
     def __repr__(self) -> str:
         return (f"VetMux(backend={self.engine.backend!r}, "
@@ -203,8 +207,10 @@ class VetMux:
 
     def set_tracer(self, tracer, tid: int = 0) -> None:
         """Attach (or detach, with ``None``) a ``repro.obs.Tracer``.  Spans
-        from this mux — and from its engine and every stream it drains —
-        land on lane ``tid`` (the shard index in a sharded fleet)."""
+        from this mux — and from its engine — land on lane ``tid`` (the
+        shard index in a sharded fleet).  The anomaly monitor's per-scan
+        spans are attached on their own (``mux.monitor.set_tracer``): at
+        fleet sizes they would outnumber every other span."""
         self.tracer = tracer
         self.trace_tid = int(tid)
         self.engine.set_tracer(tracer, tid=tid)
@@ -304,7 +310,8 @@ class VetMux:
                         rows=self._rows, padded_rows=self._padded_rows,
                         deferred=self._deferred, streams=len(self._members),
                         anomalies=(self.monitor.raised
-                                   if self.monitor is not None else 0))
+                                   if self.monitor is not None else 0),
+                        pressure_ticks=self._pressure_ticks)
 
     # ------------------------------------------------------------- ingest
     def feed(self, stream_id: Hashable, times) -> int:
@@ -332,7 +339,12 @@ class VetMux:
             >>> mux.feed("w0", np.linspace(1e-3, 2e-3, 100))  # 6x the ring
             100
         """
-        return self.stream(stream_id).feed(times, on_pressure=self.tick)
+        return self.stream(stream_id).feed(times,
+                                           on_pressure=self._pressure_tick)
+
+    def _pressure_tick(self) -> MuxTick:
+        self._pressure_ticks += 1
+        return self.tick()
 
     # -------------------------------------------------------------- tick
     def tick(self) -> MuxTick:
@@ -541,6 +553,7 @@ class VetMux:
                 "ticks": self._ticks, "dispatches": self._dispatches,
                 "rows": self._rows, "padded_rows": self._padded_rows,
                 "deferred": self._deferred,
+                "pressure_ticks": self._pressure_ticks,
             },
             "monitor": (self.monitor.state_dict()
                         if self.monitor is not None else None),
@@ -568,6 +581,7 @@ class VetMux:
         self._rows = c["rows"]
         self._padded_rows = c["padded_rows"]
         self._deferred = c["deferred"]
+        self._pressure_ticks = c.get("pressure_ticks", 0)
         # Monitor state rides along so restored muxes neither re-flag old
         # shifts nor lose the anomaly count (``stats`` equality after a
         # round trip).  Snapshots predating the monitor restore to a fresh

@@ -525,7 +525,8 @@ class ShardedVetMux:
                         padded_rows=sum(s.padded_rows for s in per),
                         deferred=sum(s.deferred for s in per),
                         streams=len(self._placed),
-                        anomalies=sum(s.anomalies for s in per))
+                        anomalies=sum(s.anomalies for s in per),
+                        pressure_ticks=sum(s.pressure_ticks for s in per))
 
     @property
     def shard_stats(self) -> Tuple[MuxStats, ...]:

@@ -779,7 +779,8 @@ class TransportVetMux:
                         streams=len(self._placer.placed),
                         retries=sum(h.retries for h in self._handles),
                         respawns=sum(h.respawns for h in self._handles),
-                        anomalies=sum(s.anomalies for s in per))
+                        anomalies=sum(s.anomalies for s in per),
+                        pressure_ticks=sum(s.pressure_ticks for s in per))
 
     @property
     def shard_stats(self) -> Tuple[MuxStats, ...]:

@@ -32,7 +32,10 @@ from jax.experimental import pallas as pl
 
 from ..runtime import resolve_interpret
 
-__all__ = ["sse_scan", "DEFAULT_BLOCK"]
+__all__ = ["sse_scan", "DEFAULT_BLOCK", "KERNEL_NAME"]
+
+# The kernel's name in the compiled program and the profiler's trace.
+KERNEL_NAME = "changepoint_sse"
 
 DEFAULT_BLOCK = 1024
 
@@ -111,4 +114,5 @@ def sse_scan(cy, cyy, cxy, sx1, sxx1, sx2, sxx2, totals, *, true_n: int,
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(cy, cyy, cxy, sx1, sxx1, sx2, sxx2, totals)

@@ -81,7 +81,12 @@ from jax.experimental import pallas as pl
 
 from ..runtime import resolve_interpret
 
-__all__ = ["fused_window_vet_scan", "BLOCK_ROWS", "LANES"]
+__all__ = ["fused_window_vet_scan", "BLOCK_ROWS", "KERNEL_NAME", "LANES"]
+
+# The kernel's name in the compiled program and the profiler's trace (its
+# op there is ``windowvet`` or ``windowvet.<n>``), apart from the gather
+# that the same program runs before it.
+KERNEL_NAME = "windowvet"
 
 BLOCK_ROWS = 8  # rows (windows) per grid step
 LANES = 8  # output lanes per row: [vet, ei, oc, pr, t, n, pad, pad]
@@ -265,4 +270,5 @@ def fused_window_vet_scan(arena, starts, lengths, pr, *, lmax: int,
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(windows, lengths[:, None], pr[:, None])

@@ -20,6 +20,11 @@ Padding contract:
 - the arena pads to a pow2 at least ``arena + lmax`` so every row's
   ``lmax``-wide device gather stays in bounds (out-of-range gather indices
   would clamp — padding keeps clamping from ever triggering).
+
+Spans (``repro.obs``, with a tracer passed in): ``vet.stage`` (prefix
+sums, padding, the f32 arena), ``vet.launch`` (``device_put`` and the
+enqueue), ``vet.wait`` (until the result is ready on the device) and
+``vet.fetch`` (the copy to the host and the unpacking).
 """
 
 from __future__ import annotations
@@ -29,6 +34,7 @@ from typing import NamedTuple
 import jax
 import numpy as np
 
+from ...obs.trace import span as _span
 from ..runtime import resolve_interpret
 from .kernel import BLOCK_ROWS, fused_window_vet_scan
 
@@ -64,7 +70,8 @@ def staged_bytes(arena_len: int, rows: int, max_len: int) -> int:
 
 def fused_window_vet(arena, starts, lengths, *, omega: int = 3,
                      cut_space: str = "log", interpret=None,
-                     block_rows: int = BLOCK_ROWS, device=None):
+                     block_rows: int = BLOCK_ROWS, device=None,
+                     tracer=None, tid: int = 0):
     """Vet every window ``arena[starts[r] : starts[r] + lengths[r])`` fused.
 
     Args:
@@ -79,6 +86,8 @@ def fused_window_vet(arena, starts, lengths, *, omega: int = 3,
         block_rows: kernel rows per grid step.
         device: ``jax.Device`` to commit the launch inputs to (``None`` =
             JAX's default device).
+        tracer / tid: ``repro.obs.Tracer`` and lane for the ``vet.*``
+            spans (``None``: no spans).
 
     Returns:
         ``FusedVet``: ``(vet, ei, oc, pr, t, n)`` host arrays, one entry
@@ -98,42 +107,48 @@ def fused_window_vet(arena, starts, lengths, *, omega: int = 3,
     if starts.min() < 0 or (starts + lengths).max() > a64.size:
         raise ValueError("window out of arena bounds")
 
-    # Ring prefix sums, one f64 pass over the arena: every window's PR is a
-    # difference of two entries.
-    ps = np.concatenate([[0.0], np.cumsum(a64)])
-    pr64 = ps[starts + lengths] - ps[starts]
+    with _span(tracer, "vet.stage", tid=tid):
+        # Ring prefix sums, one f64 pass over the arena: every window's PR
+        # is a difference of two entries.
+        ps = np.concatenate([[0.0], np.cumsum(a64)])
+        pr64 = ps[starts + lengths] - ps[starts]
 
-    lmax = max(8, _pow2(int(lengths.max())))
-    rows_p = max(block_rows, _pow2(rows))
-    pad = rows_p - rows
-    if pad:
-        starts_p = np.concatenate([starts, np.repeat(starts[-1:], pad)])
-        lengths_p = np.concatenate([lengths, np.repeat(lengths[-1:], pad)])
-        pr_p = np.concatenate([pr64, np.repeat(pr64[-1:], pad)])
-    else:
-        starts_p, lengths_p, pr_p = starts, lengths, pr64
+        lmax = max(8, _pow2(int(lengths.max())))
+        rows_p = max(block_rows, _pow2(rows))
+        pad = rows_p - rows
+        if pad:
+            starts_p = np.concatenate([starts, np.repeat(starts[-1:], pad)])
+            lengths_p = np.concatenate([lengths,
+                                        np.repeat(lengths[-1:], pad)])
+            pr_p = np.concatenate([pr64, np.repeat(pr64[-1:], pad)])
+        else:
+            starts_p, lengths_p, pr_p = starts, lengths, pr64
 
-    alen = _pow2(a64.size + lmax)
-    arena_f32 = np.zeros(alen, dtype=np.float32)
-    arena_f32[:a64.size] = a64
+        alen = _pow2(a64.size + lmax)
+        arena_f32 = np.zeros(alen, dtype=np.float32)
+        arena_f32[:a64.size] = a64
 
-    inputs = (arena_f32, starts_p.astype(np.int32),
-              lengths_p.astype(np.int32), pr_p.astype(np.float32))
-    if device is not None:
-        inputs = jax.device_put(inputs, device)
-    out = fused_window_vet_scan(
-        *inputs,
-        lmax=lmax,
-        block_rows=block_rows,
-        omega=omega,
-        log_space=(cut_space == "log"),
-        interpret=resolve_interpret(interpret),
-    )
-    ran_on = next(iter(out.devices()))
-    out = np.asarray(out)[:rows]
-    ei = out[:, 1].astype(np.float64)
-    oc = out[:, 2].astype(np.float64)
-    # PR (and vet's numerator) from the f64 ring prefix sums — exact to f32
-    # rounding, matching the scalar oracle's sum to well under 1e-5.
-    return FusedVet(pr64 / ei, ei, oc, pr64, out[:, 4].astype(np.int32),
-                    lengths.astype(np.int64), ran_on)
+        inputs = (arena_f32, starts_p.astype(np.int32),
+                  lengths_p.astype(np.int32), pr_p.astype(np.float32))
+    with _span(tracer, "vet.launch", tid=tid):
+        if device is not None:
+            inputs = jax.device_put(inputs, device)
+        out = fused_window_vet_scan(
+            *inputs,
+            lmax=lmax,
+            block_rows=block_rows,
+            omega=omega,
+            log_space=(cut_space == "log"),
+            interpret=resolve_interpret(interpret),
+        )
+    with _span(tracer, "vet.wait", tid=tid):
+        out.block_until_ready()
+    with _span(tracer, "vet.fetch", tid=tid):
+        ran_on = next(iter(out.devices()))
+        out = np.asarray(out)[:rows]
+        ei = out[:, 1].astype(np.float64)
+        oc = out[:, 2].astype(np.float64)
+        # PR (and vet's numerator) from the f64 ring prefix sums — exact to
+        # f32 rounding, matching the scalar oracle's sum to well under 1e-5.
+        return FusedVet(pr64 / ei, ei, oc, pr64, out[:, 4].astype(np.int32),
+                        lengths.astype(np.int64), ran_on)

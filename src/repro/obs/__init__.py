@@ -8,9 +8,15 @@ itself.  Three pieces, zero dependencies:
   with an injectable monotonic clock, a true no-op path when disabled,
   and pickle-safe records so transport workers ship their spans back on
   ``TickReply`` for cross-process reassembly (``Tracer.adopt``).  Every
-  layer — engine dispatch, stream drain/commit/collect, mux
-  plan/coalesce/dispatch/commit/anomaly, shard fan-out, transport round
-  trips — times itself through this one seam.
+  layer — mux plan/coalesce/dispatch/commit/collect/anomaly, the
+  engine's dispatch and the fused launch's ``vet.stage/launch/wait/
+  fetch`` inside it, the anomaly monitor's ``anomaly.scan`` with its
+  ``anomaly.launch``/``anomaly.wait``, shard fan-out, transport round
+  trips — times itself through this one seam.  Streams record no spans
+  of their own (one a stream a tick would swamp every other).
+  ``Tracer(annotate=True)`` also writes each span into the
+  ``jax.profiler`` trace as a ``TraceAnnotation`` of its name, on the
+  device trace's clock (jax is imported only then).
 - ``MetricsRegistry`` (``repro.obs.metrics``): counters, gauges and
   fixed-bucket histograms; a tracer wired to a registry feeds
   ``span.<name>`` duration histograms automatically.
@@ -22,10 +28,11 @@ itself.  Three pieces, zero dependencies:
   PRs are judged by.
 
 Wiring: ``VetMux(..., tracer=t)`` / ``mux.set_tracer(t)`` threads the
-tracer down to its engine and streams; ``ShardedVetMux.set_tracer`` gives
-each shard mux its own ``tid`` lane; ``TransportVetMux(..., tracer=t)``
-enables worker-side tracers over the wire and adopts their spans under
-per-worker ``pid`` lanes.  ``benchmarks/fleet_obs.py`` prices the
+tracer down to its engine (the anomaly monitor, three spans a scanned
+stream, is attached on its own: ``mux.monitor.set_tracer(t)``);
+``ShardedVetMux.set_tracer`` gives each shard mux its own ``tid`` lane;
+``TransportVetMux(..., tracer=t)`` enables worker-side tracers over the
+wire and adopts their spans under per-worker ``pid`` lanes.  ``benchmarks/fleet_obs.py`` prices the
 disabled-path overhead and commits the ledger artifact.
 """
 

@@ -1,9 +1,10 @@
 """Zero-dependency span tracer — the fleet's one timing seam.
 
-Every layer of the estimation stack (engine dispatch, stream drain/commit/
-collect, mux plan/coalesce/dispatch/commit, shard fan-out, transport round
-trips) times itself through this module, so "where does a tick's time go?"
-has exactly one answer and one clock.  Design constraints, in order:
+Every layer of the estimation stack (mux plan/coalesce/dispatch/commit/
+collect/anomaly, the engine's dispatch and the fused launch inside it, the
+anomaly monitor's scans, shard fan-out, transport round trips) times
+itself through this module, so "where does a tick's time go?" has exactly
+one answer and one clock.  Design constraints, in order:
 
 - **Cheap when disabled.**  Instrumented call sites never branch on a
   feature flag; they call ``span(tracer, name, ...)`` with ``tracer=None``
@@ -19,6 +20,12 @@ has exactly one answer and one clock.  Design constraints, in order:
   through ``timed(tracer, ...)`` — the tracer's clock when present, the
   same ``perf_counter`` otherwise — so there is one clock source, not a
   tracer clock plus ad-hoc ``perf_counter`` pairs that could disagree.
+- **One clock with the device trace.**  ``Tracer(annotate=True)`` also
+  enters a ``jax.profiler.TraceAnnotation`` of each span's name, so a
+  profiler trace taken meanwhile holds every span on its own host plane,
+  on the same clock as the device's operations.  jax is imported only
+  then: with ``annotate`` off this module needs nothing beyond the
+  standard library.
 - **Cross-process reassembly.**  Spans are plain ``SpanRecord`` NamedTuples
   (pickle-safe), so a transport shard worker drains its tracer into the
   ``TickReply`` and the driver ``adopt``s the records under the worker's
@@ -35,7 +42,7 @@ inference.
 from __future__ import annotations
 
 import time
-from typing import Callable, Dict, Iterable, List, NamedTuple, Optional
+from typing import Callable, Dict, Iterable, List, NamedTuple, Optional, Union
 
 __all__ = ["SpanRecord", "Tracer", "span", "timed"]
 
@@ -102,7 +109,7 @@ class _Span:
     (``elapsed_s``, ``vet_s``) read it instead of re-timing."""
 
     __slots__ = ("_tracer", "name", "tid", "_attrs", "sid", "parent",
-                 "_t0", "dur")
+                 "_t0", "dur", "_mirror")
 
     def __init__(self, tracer: "Tracer", name: str, tid: int, attrs: dict):
         self._tracer = tracer
@@ -127,12 +134,17 @@ class _Span:
             stack = tr._stacks[self.tid] = []
         self.parent = stack[-1].sid if stack else None
         stack.append(self)
+        if tr._annotation is not None:
+            self._mirror = tr._annotation(self.name)
+            self._mirror.__enter__()
         self._t0 = tr.clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         tr = self._tracer
         self.dur = tr.clock() - self._t0
+        if tr._annotation is not None:
+            self._mirror.__exit__(*exc)
         stack = tr._stacks[self.tid]
         if stack and stack[-1] is self:
             stack.pop()
@@ -156,6 +168,11 @@ class Tracer:
             completed span feeds ``span.<name>`` (duration histogram,
             seconds) and ``span.<name>.count`` automatically, so metrics
             ride the same seam as spans.
+        annotate: ``True`` mirrors every span into a
+            ``jax.profiler.TraceAnnotation`` of its name (jax is imported
+            here, and only then); a callable ``name -> context manager``
+            is used in its place (tests).  Adopted records are not
+            mirrored.
 
     Example::
 
@@ -169,8 +186,13 @@ class Tracer:
     """
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter, *,
-                 pid: int = 0, metrics=None):
+                 pid: int = 0, metrics=None,
+                 annotate: Union[bool, Callable] = False):
         self.clock = clock
+        if annotate is True:
+            from jax.profiler import TraceAnnotation
+            annotate = TraceAnnotation
+        self._annotation: Optional[Callable] = annotate or None
         self.pid = int(pid)
         self.metrics = metrics
         self.records: List[SpanRecord] = []  # completion order

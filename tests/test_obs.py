@@ -16,6 +16,10 @@ Four layers of guarantees:
   all three backends.
 """
 
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -164,6 +168,61 @@ def test_timed_always_measures():
     assert sw.dur == 1.0  # the tracer clock, not wall time
     (rec,) = tr.records
     assert rec.name == "x" and rec.tid == 2 and ("op", "tick") in rec.attrs
+
+
+# ------------------------------------------------- the profiler's trace
+
+
+def test_annotate_mirrors_every_span_around_its_interval():
+    """``annotate`` enters an annotation of the span's name before the
+    span's clock starts and leaves it after the clock stops, nested as the
+    spans are; an exception reaches both."""
+    events = []
+    clock = fake_clock()
+
+    class Note:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            events.append(("enter", self.name, clock()))
+
+        def __exit__(self, *exc):
+            events.append(("exit", self.name, clock(), exc[0]))
+
+    tr = Tracer(clock=clock, annotate=Note)
+    with tr.span("tick"):
+        with tr.span("dispatch", rows=3):
+            pass
+    with pytest.raises(ValueError):
+        with tr.span("boom"):
+            raise ValueError("x")
+    assert [e[:2] for e in events] == [
+        ("enter", "tick"), ("enter", "dispatch"), ("exit", "dispatch"),
+        ("exit", "tick"), ("enter", "boom"), ("exit", "boom")]
+    assert events[-1][3] is ValueError
+    for r in tr.records:
+        (t_in,) = [e[2] for e in events if e[:2] == ("enter", r.name)]
+        (t_out,) = [e[2] for e in events if e[:2] == ("exit", r.name)]
+        assert t_in < r.ts and r.ts + r.dur < t_out
+    # The records are what an unannotated tracer records.
+    assert [(r.name, r.parent) for r in tr.records] == [
+        ("dispatch", 0), ("tick", None), ("boom", None)]
+
+
+def test_annotate_uses_the_profiler_and_jax_only_then():
+    code = ("import sys; from repro.obs import Tracer, span; "
+            "t = Tracer(); "
+            "assert 'jax' not in sys.modules, 'imported jax'; "
+            "t = Tracer(annotate=True); "
+            "from jax.profiler import TraceAnnotation; "
+            "assert t._annotation is TraceAnnotation; "
+            "s = t.span('a'); s.__enter__(); s.__exit__(None, None, None); "
+            "assert [r.name for r in t.records] == ['a']")
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True)
+    assert out.returncode == 0, out.stderr
 
 
 # ------------------------------------------------------------------- metrics
@@ -346,19 +405,30 @@ def _feed_all(mux, n=8, chunk=24, seed=0):
         mux.feed(f"w{w}", rng.standard_normal(chunk) ** 2 + 1e-3)
 
 
+def _by_name(records):
+    by_name = {}
+    for r in records:
+        by_name.setdefault(r.name, []).append(r)
+    return by_name, {r.sid: r.name for r in records}
+
+
 def test_mux_tick_span_tree():
     tr = Tracer(clock=fake_clock())
     mux = VetMux(VetEngine("numpy", buckets=16), tracer=tr)
-    _feed_all(mux)
+    # The mux's tracer leaves the monitor alone; it is attached on its own.
+    assert mux.monitor.tracer is None
+    mux.monitor.set_tracer(tr)
+    # 9 windows a stream: enough for the monitor (6 points) to scan.
+    _feed_all(mux, chunk=40)
     mux.tick()
-    by_name = {}
-    for r in tr.records:
-        by_name.setdefault(r.name, []).append(r)
-    sid_name = {r.sid: r.name for r in tr.records}
+    by_name, sid_name = _by_name(tr.records)
     assert {"mux.tick", "mux.plan", "mux.coalesce", "mux.dispatch",
             "mux.commit", "mux.collect", "mux.anomaly",
-            "engine.dispatch", "stream.drain", "stream.commit",
-            "stream.collect"} <= set(by_name)
+            "engine.dispatch", "anomaly.scan", "anomaly.launch",
+            "anomaly.wait"} <= set(by_name)
+    # Streams record no spans of their own: one a stream a tick would
+    # outnumber every other span at fleet sizes.
+    assert not any(n.startswith("stream.") for n in by_name)
     (tick,) = by_name["mux.tick"]
     assert tick.parent is None
     for name in ("mux.plan", "mux.coalesce", "mux.dispatch", "mux.commit",
@@ -369,20 +439,67 @@ def test_mux_tick_span_tree():
         assert sid_name[r.parent] == "mux.dispatch"
         attrs = dict(r.attrs)
         assert attrs["bytes"] > 0 and attrs["backend"] == "numpy"
-    for r in by_name["stream.drain"]:
-        assert sid_name[r.parent] == "mux.coalesce"
+    # One scan a stream, each with its launch and its wait inside.
+    assert len(by_name["anomaly.scan"]) == 8
+    for r in by_name["anomaly.scan"]:
+        assert sid_name[r.parent] == "mux.anomaly"
+    for name in ("anomaly.launch", "anomaly.wait"):
+        assert len(by_name[name]) == 8
+        for r in by_name[name]:
+            assert sid_name[r.parent] == "anomaly.scan", name
     # The whole tree exports and nests cleanly.
     assert validate_chrome(to_chrome(tr.records)) == []
 
 
-def test_traced_mux_results_identical_to_untraced():
+@pytest.mark.parametrize("backend", ["numpy", "pallas"])
+def test_monitor_and_fused_launch_span_trees(backend):
+    """The monitor's scan tree on either argmin backend, and on the fused
+    path (pallas, interpreted here) the launch's phases inside
+    ``engine.dispatch``."""
+    from repro.fleet import AnomalyMonitor
+    tr = Tracer()
+    mux = VetMux(VetEngine(backend, buckets=16),
+                 monitor=AnomalyMonitor(backend), tracer=tr)
+    mux.monitor.set_tracer(tr)
+    _feed_all(mux, n=4, chunk=40)
+    mux.tick()
+    by_name, sid_name = _by_name(tr.records)
+    assert len(by_name["anomaly.scan"]) == 4
+    for name in ("anomaly.launch", "anomaly.wait"):
+        assert [sid_name[r.parent] for r in by_name[name]] == \
+            ["anomaly.scan"] * 4
+    vet = [n for n in by_name if n.startswith("vet.")]
+    if backend == "numpy":
+        assert vet == []
+        return
+    (dispatch,) = by_name["engine.dispatch"]
+    assert dict(dispatch.attrs)["kind"] == "fused"
+    phases = sorted((r for r in tr.records if r.name.startswith("vet.")),
+                    key=lambda r: r.ts)
+    assert [r.name for r in phases] == ["vet.stage", "vet.launch",
+                                        "vet.wait", "vet.fetch"]
+    assert all(r.parent == dispatch.sid for r in phases)
+    assert sum(r.dur for r in phases) <= dispatch.dur
+    assert validate_chrome(to_chrome(tr.records)) == []
+
+
+@pytest.mark.parametrize("name", ["mixed_windows", "contention_onset"])
+def test_traced_mux_results_identical_to_untraced(name):
+    """With the monitor on (numpy, the mux's default), a traced mux vets
+    and flags exactly as an untraced one; ``contention_onset`` raises
+    flags."""
     plain = VetMux(VetEngine("numpy", buckets=16))
     traced = VetMux(VetEngine("numpy", buckets=16), tracer=Tracer())
-    scenario = build("mixed_windows", n_workers=16, n_ticks=4, seed=1)
+    traced.monitor.set_tracer(traced.tracer)
+    scenario = build(name, n_workers=16,
+                     n_ticks=4 if name == "mixed_windows" else 16, seed=1)
     ticks_p = play(scenario, plain)
     ticks_t = play(scenario, traced)
+    if name == "contention_onset":
+        assert any(t.flags for t in ticks_p)
     for tp, tt in zip(ticks_p, ticks_t):
         assert tp.dispatches == tt.dispatches and tp.rows == tt.rows
+        assert tp.flags == tt.flags
         assert set(tp.results) == set(tt.results)
         for sid, rp in tp.results.items():
             rt = tt.results[sid]
@@ -391,7 +508,28 @@ def test_traced_mux_results_identical_to_untraced():
             else:
                 np.testing.assert_array_equal(rp.vet, rt.vet)
                 np.testing.assert_array_equal(rp.ei, rt.ei)
-    assert plain.stats.dispatches == traced.stats.dispatches
+    assert plain.stats == traced.stats
+    if name == "contention_onset":  # long enough for the monitor to scan
+        assert {r.name for r in traced.tracer.records} >= {"anomaly.scan"}
+
+
+def test_one_scan_span_a_scan():
+    """An observation that leaves the ring short of ``min_points`` scans
+    nothing and records no span; each later one is one scan, one span.
+    Unattached, the monitor records nothing."""
+    from repro.fleet import AnomalyMonitor
+    tr = Tracer()
+    mon = AnomalyMonitor("numpy", ring=16, min_points=10)
+    vets = np.random.default_rng(5).lognormal(0.0, 0.3, 40)
+    mon.observe("s", vets[:4], first=0)
+    mon.set_tracer(tr)
+    spans = []
+    for seen in (8, 9, 12, 20, 20, 40):
+        mon.observe("s", vets[:seen], first=0)
+        spans.append(sum(r.name == "anomaly.scan" for r in tr.records))
+    # 8 and 9 windows: too few; 12, 20, 40: a scan each; 20 again: none.
+    assert spans == [0, 0, 1, 2, 2, 3]
+    assert [r.name for r in tr.records].count("anomaly.wait") == 3
 
 
 def test_sharded_mux_uses_shard_lanes():
@@ -494,6 +632,58 @@ def test_transport_untraced_replies_ship_no_spans():
         _feed_all(fleet, n=4)
         reply = fleet._handles[0].call("tick", None)
         assert reply.spans == ()
+
+
+# ------------------------------------------------------ program counters
+
+
+def test_pressure_ticks_are_the_ticks_feed_takes():
+    mux = VetMux(VetEngine("numpy", buckets=16), monitor=False)
+    mux.register("w0", window=8, stride=4, capacity=16)
+    mux.register("w1", window=8, stride=4, capacity=16)
+    ticks = mux.stats.ticks
+    mux.feed("w0", np.linspace(1e-3, 2e-3, 100))  # 6x the ring
+    taken = mux.stats.ticks - ticks
+    assert taken > 0 and mux.stats.pressure_ticks == taken
+    mux.tick()  # a tick of the caller's is no pressure tick
+    assert mux.stats.pressure_ticks == taken
+    twin = VetMux(VetEngine("numpy", buckets=16), monitor=False)
+    twin.load_state_dict(mux.state_dict())
+    assert twin.stats == mux.stats
+    fleet = ShardedVetMux(2, backend="numpy")
+    for k in range(4):
+        fleet.register(f"w{k}", window=8, stride=4, capacity=16)
+    for k in range(4):
+        fleet.feed(f"w{k}", np.linspace(1e-3, 2e-3, 40))
+    assert fleet.stats.pressure_ticks == sum(
+        s.pressure_ticks for s in fleet.shard_stats) > 0
+
+
+def _scans_too_few(marks, ring, confirm, last):
+    """Brute force: windows ``j <= last - ring`` whose scans (watermarks
+    in ``(j, j + ring]``) number fewer than ``confirm``."""
+    return sum(sum(j < w <= j + ring for w in marks) < confirm
+               for j in range(0, last - ring + 1))
+
+
+def test_underscanned_counts_windows_scanned_too_seldom():
+    from repro.fleet import AnomalyMonitor
+    rng = np.random.default_rng(4)
+    mon = AnomalyMonitor("numpy", ring=8, omega=2, confirm=3)
+    vets = rng.lognormal(0.0, 0.3, 400)
+    seen, marks = 0, []
+    for step in range(60):
+        if step == 30:  # a snapshot in the middle carries on exactly
+            twin = AnomalyMonitor("numpy", ring=8, omega=2, confirm=3)
+            twin.load_state_dict(mon.state_dict())
+            mon = twin
+        # The first brings a full ring; later ones 1..9 windows (a ring
+        # of 8 sees some of those windows once only).
+        seen += 8 if step == 0 else int(rng.integers(1, 10))
+        mon.observe("s", vets[:seen], first=0)
+        marks.append(seen)
+        assert mon.underscanned == _scans_too_few(marks, 8, 3, seen), step
+    assert 0 < mon.underscanned < seen - 8
 
 
 # ------------------------------------------------------- recorder compat
