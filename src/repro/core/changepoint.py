@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["index_closed_forms", "two_segment_sse", "estimate_changepoint",
-           "segment_sse_terms"]
+           "estimate_changepoint_rows", "segment_sse_terms"]
 
 
 def _promote(y: jax.Array) -> jax.Array:
@@ -147,6 +147,14 @@ def estimate_changepoint(y_sorted: jax.Array, omega: int = 3) -> jax.Array:
             f"split (omega={omega} on each side), got n={n}")
     sse = two_segment_sse(y_sorted, omega=omega)
     return (jnp.argmin(sse) + 1).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("omega",))
+def estimate_changepoint_rows(y_rows: jax.Array, omega: int = 3) -> jax.Array:
+    """``estimate_changepoint`` on every row of ``(rows, n)``: int32 t-hat
+    a row, in one program."""
+    return jax.vmap(functools.partial(estimate_changepoint, omega=omega))(
+        y_rows)
 
 
 def estimate_changepoint_naive(y_sorted, omega: int = 3) -> int:

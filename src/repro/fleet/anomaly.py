@@ -23,12 +23,19 @@ Anomalies in Hadoop" (arXiv:1505.01919) and are modeled one-to-one in
 
 Detection ladder: the monitor accepts the same three backends as the engine
 (``method="numpy" | "jax" | "pallas"``).  The numpy method is the f64
-oracle scan; jax runs ``core.changepoint.estimate_changepoint``; pallas
-runs ``kernels.changepoint.changepoint_pallas``.  Confidence and the
-pre/post levels are always computed host-side in f64 (rings are <= a few
-dozen points — the backend choice only moves the argmin search), so the
+oracle scan; jax runs ``core.changepoint.estimate_changepoint_rows``;
+pallas runs ``kernels.changepoint.changepoint_pallas_rows``.  Each row of
+those gets the cut its ring alone would get.  Confidence and the pre/post
+levels are always computed host-side in f64 (rings are <= a few dozen
+points — the backend choice only moves the argmin search), so the
 differential suites can require onset agreement across all three within
 the scenario bank's +/-2-tick tolerance.
+
+Batching: a mux tick first hands every stream to ``prepare``, which scans
+all the rings that got new windows as one ``(rows, ring)`` matrix a ring
+length (one launch, one fetch), and then calls ``observe`` stream by
+stream, which takes in the windows and applies the gates to the prepared
+scan.  A lone ``observe`` is the same path with one row.
 
 Heavy-tail hardening — window vets inherit the overhead channel's Pareto
 tail, so a naive mean-shift test on raw vets flags every lucky straggler
@@ -66,7 +73,8 @@ from __future__ import annotations
 
 from bisect import bisect_right
 from collections import deque
-from typing import Deque, Dict, Hashable, List, NamedTuple, Optional, Tuple
+from typing import (Deque, Dict, Hashable, Iterable, List, NamedTuple,
+                    Optional, Tuple)
 
 import numpy as np
 
@@ -100,12 +108,13 @@ class RegimeShift(NamedTuple):
 def _closed_form_scan_f64(y: np.ndarray, omega: int) -> np.ndarray:
     """f64 numpy mirror of ``core.changepoint.two_segment_sse``: the SSE of
     the best two-segment linear fit for every candidate prefix length k
-    (+inf outside the probing window)."""
-    n = y.size
+    (+inf outside the probing window), along the last axis (one ring, or
+    one a row)."""
+    n = y.shape[-1]
     k = np.arange(1, n + 1, dtype=np.float64)
-    cy = np.cumsum(y)
-    cyy = np.cumsum(y * y)
-    cxy = np.cumsum(k * y)
+    cy = np.cumsum(y, axis=-1)
+    cyy = np.cumsum(y * y, axis=-1)
+    cxy = np.cumsum(k * y, axis=-1)
     sx1 = k * (k + 1.0) / 2.0
     sxx1 = k * (k + 1.0) * (2.0 * k + 1.0) / 6.0
     nf = float(n)
@@ -123,26 +132,49 @@ def _closed_form_scan_f64(y: np.ndarray, omega: int) -> np.ndarray:
         return np.maximum(sse, 0.0)
 
     sse = (seg(k, sx1, cy, sxx1, cxy, cyy)
-           + seg(nf - k, sx_tot - sx1, cy[-1] - cy, sxx_tot - sxx1,
-                 cxy[-1] - cxy, cyy[-1] - cyy))
+           + seg(nf - k, sx_tot - sx1, cy[..., -1:] - cy, sxx_tot - sxx1,
+                 cxy[..., -1:] - cxy, cyy[..., -1:] - cyy))
     valid = (k >= omega) & (k <= nf - omega)
     return np.where(valid, sse, np.inf)
 
 
-def _single_segment_sse_f64(y: np.ndarray) -> float:
-    """SSE of one linear fit over the whole ring (the null model)."""
-    n = y.size
+def _single_segment_sse_f64(y: np.ndarray) -> np.ndarray:
+    """SSE of one linear fit over the whole ring (the null model), along
+    the last axis."""
+    n = y.shape[-1]
     k = np.arange(1, n + 1, dtype=np.float64)
-    sy, syy, sxy = y.sum(), (y * y).sum(), (k * y).sum()
+    sy, syy, sxy = y.sum(-1), (y * y).sum(-1), (k * y).sum(-1)
     nf = float(n)
     sx = nf * (nf + 1.0) / 2.0
     sxx = nf * (nf + 1.0) * (2.0 * nf + 1.0) / 6.0
     sxx_c = sxx - sx * sx / nf
     syy_c = syy - sy * sy / nf
     if sxx_c <= 0.0:
-        return max(float(syy_c), 0.0)
+        return np.maximum(syy_c, 0.0)
     sxy_c = sxy - sx * sy / nf
-    return max(float(syy_c - sxy_c * sxy_c / sxx_c), 0.0)
+    return np.maximum(syy_c - sxy_c * sxy_c / sxx_c, 0.0)
+
+
+def _intake(st: "_StreamState", v: np.ndarray, first: int):
+    """What observing ``v`` (window ``first`` on) does to ``st``: whether
+    the ring restarts at ``first`` (a rewind, after a stream reset or a
+    restore, or a gap, windows evicted before the monitor saw them), and
+    the windows that are new."""
+    restart = first + v.size < st.seen or first > st.seen
+    return restart, v[0 if restart else st.seen - first:]
+
+
+class _Cut(NamedTuple):
+    """One scan's outcome, before the gates: the ring's watermark and base
+    it was made at, the cut ``t`` (1-indexed prefix length within the
+    ring), the levels either side and the confidence."""
+
+    seen: int
+    base: int
+    t: int
+    pre: float
+    post: float
+    confidence: float
 
 
 class _StreamState:
@@ -180,8 +212,8 @@ class AnomalyMonitor:
 
     Args:
         method: argmin backend — ``"numpy"`` (f64 oracle scan), ``"jax"``
-            (``core.changepoint.estimate_changepoint``) or ``"pallas"``
-            (``kernels.changepoint.changepoint_pallas``).
+            (``core.changepoint.estimate_changepoint_rows``) or ``"pallas"``
+            (``kernels.changepoint.changepoint_pallas_rows``).
         ring: newest window vets retained per stream (bounded memory for
             serve loops that live forever).
         omega: probing-window margin, as in ``core.changepoint``.
@@ -206,16 +238,19 @@ class AnomalyMonitor:
     same stream (e.g. the restart edge after a failure) flags again.
 
     Observability: with a ``repro.obs.Tracer`` attached (``set_tracer``;
-    ``VetMux.set_tracer`` leaves the monitor alone, since these are three
-    spans a scanned stream a tick), every scan is one ``anomaly.scan``
-    span whose own time is the
-    host's work (ring update, log vets, levels, the f64 SSE landscape, the
-    gates), with two children: ``anomaly.launch``, the call into the
-    argmin backend (on jax and pallas it returns before the device is
-    done), and ``anomaly.wait``, bringing the argmin to the host.
-    ``underscanned`` counts windows whose last scan has been made after
-    fewer than ``confirm`` scans: a shift there could not be confirmed,
-    which no latency shows.
+    ``VetMux.set_tracer`` leaves the monitor alone, since there is a span
+    a scanned stream a tick), every scan is one ``anomaly.scan`` span (ring
+    update, gates), and every batch of scans one ``anomaly.batch`` span
+    (log vets, levels, the f64 SSE landscape) with, for each ring length,
+    ``anomaly.launch``, the call into the argmin backend (on jax and
+    pallas it returns before the device is done), and ``anomaly.wait``,
+    bringing the cuts to the host, both with ``rows`` (the rings).  In a
+    mux tick the batch comes before the scans; a lone ``observe`` makes
+    its batch of one inside its scan.  ``batched_scans`` and
+    ``single_scans`` count the scans whose cut came from ``prepare`` and
+    those launched alone.  ``underscanned`` counts windows whose last scan
+    has been made after fewer than ``confirm`` scans: a shift there could
+    not be confirmed, which no latency shows.
     """
 
     def __init__(self, method: str = "numpy", *, ring: int = 64,
@@ -238,6 +273,10 @@ class AnomalyMonitor:
         self._streams: Dict[Hashable, _StreamState] = {}
         self._raised = 0
         self._underscanned = 0
+        self._batched_scans = 0
+        self._single_scans = 0
+        # stream -> its scan from the last ``prepare``, for ``observe``.
+        self._prepared: Dict[Hashable, _Cut] = {}
         self.tracer = None
         self.trace_tid = 0
 
@@ -256,6 +295,17 @@ class AnomalyMonitor:
         next window evicts them from the ring) after fewer than
         ``confirm`` scans."""
         return self._underscanned
+
+    @property
+    def batched_scans(self) -> int:
+        """Lifetime count of scans whose cut came from a ``prepare`` batch."""
+        return self._batched_scans
+
+    @property
+    def single_scans(self) -> int:
+        """Lifetime count of scans ``observe`` launched alone (no
+        ``prepare`` before it had scanned that ring)."""
+        return self._single_scans
 
     def set_tracer(self, tracer, tid: int = 0) -> None:
         """Attach (or detach, with ``None``) a ``repro.obs.Tracer``; spans
@@ -288,12 +338,9 @@ class AnomalyMonitor:
         st = self._streams.get(stream_id)
         if st is None:
             st = self._streams[stream_id] = _StreamState(self.confirm)
-        vetted = first + v.size  # stream's vetted-window watermark
-        if vetted < st.seen or first > st.seen:
-            # Rewind (stream reset / checkpoint restore) or a gap (windows
-            # evicted before we saw them): restart the ring at this span.
+        restart, new = _intake(st, v, first)
+        if restart:
             st.reset(base=first, seen=first)
-        new = v[st.seen - first:]
         if not new.size:
             # No fresh windows: rescanning the same ring would let a noise
             # cut "confirm" itself without new evidence.
@@ -303,8 +350,8 @@ class AnomalyMonitor:
         scans = min(len(st.ring) + new.size, self.ring) >= self.min_points
         with _span(self.tracer if scans else None, "anomaly.scan",
                    tid=self.trace_tid):
-            st.ring.extend(float(x) for x in new)
-            st.seen = vetted
+            st.ring.extend(new.tolist())
+            st.seen = first + v.size  # the stream's vetted-window watermark
             drop = len(st.ring) - self.ring
             if drop > 0:
                 del st.ring[:drop]
@@ -326,26 +373,119 @@ class AnomalyMonitor:
                 self._underscanned += 1
         st.settled = max(st.settled, last + 1)
 
+    def prepare(self, entries: Iterable[Tuple[Hashable, object, int]]
+                ) -> None:
+        """Scan, in one batch a ring length, the rings that ``observe``
+        will scan for ``entries``, and keep each ring's scan for it.
+
+        ``entries`` holds ``(stream_id, vets, first)``, as ``observe``
+        will be called next, for every stream (``vets`` ``None`` where a
+        stream has no window yet).  No stream's state changes: ``observe``
+        still takes in the windows and applies the gates, and uses the kept
+        scan where its ring is the one scanned here (it launches alone
+        otherwise).  Rows are padded to the power of two at or above the
+        streams tracked or offered, so a device backend compiles one shape
+        a ring length however many streams a tick brings new windows, and
+        however many of a fleet's streams have windows yet.
+        """
+        rings: Dict[Hashable, Tuple[int, int, np.ndarray]] = {}
+        tracked = len(self._streams)
+        for sid, vets, first in entries:
+            st = self._streams.get(sid)
+            if st is None:
+                tracked += 1
+                if vets is None:
+                    continue
+                st = _StreamState(self.confirm)
+            elif vets is None or first <= st.seen == first + len(vets):
+                continue  # nothing new since the last scan
+            v = np.asarray(vets, np.float64).ravel()
+            restart, new = _intake(st, v, first)
+            if not new.size:
+                continue
+            ring = new if restart else np.concatenate((st.ring, new))
+            if min(ring.size, self.ring) < self.min_points:
+                continue
+            base = first if restart else st.base
+            base += max(ring.size - self.ring, 0)
+            rings[sid] = (first + v.size, base, ring[-self.ring:])
+        rows = 1 << max(tracked - 1, 0).bit_length()
+        self._prepared = self._batch(rings, rows)
+
+    def _batch(self, rings: Dict[Hashable, Tuple[int, int, np.ndarray]],
+               rows: int) -> Dict[Hashable, _Cut]:
+        """Scan each ring of ``rings`` (stream -> watermark, base, ring):
+        one backend call and one fetch for each ring length, rows padded to
+        ``rows`` on a device backend, then the gates' numbers in f64."""
+        groups: Dict[int, List[Hashable]] = {}
+        for sid, (_, _, ring) in rings.items():
+            groups.setdefault(ring.size, []).append(sid)
+        out: Dict[Hashable, _Cut] = {}
+        if not groups:
+            return out
+        with _span(self.tracer, "anomaly.batch", tid=self.trace_tid):
+            for n, sids in groups.items():
+                # Log vets: a regime shift multiplies the overhead channel,
+                # so it is additive here, and a single Pareto-tail spike no
+                # longer dominates the SSE.  Levels are reported back as
+                # geometric means.
+                z = np.log(np.maximum(
+                    np.array([rings[sid][2] for sid in sids]), _TINY))
+                with _span(self.tracer, "anomaly.launch", tid=self.trace_tid,
+                           rows=len(sids)):
+                    cuts = self._launch(z, rows)
+                # Host work that needs no cut, while the device scans.
+                sse = _closed_form_scan_f64(z, self.omega)
+                sse0 = _single_segment_sse_f64(z)
+                cz = np.cumsum(z, axis=1)
+                with _span(self.tracer, "anomaly.wait", tid=self.trace_tid,
+                           rows=len(sids)):
+                    t = (np.argmin(sse, axis=1) + 1 if cuts is None else
+                         np.asarray(cuts)[:len(sids)]).astype(np.int64)
+                i = np.arange(len(sids))
+                below = cz[i, t - 1]
+                pre = np.exp(below / t)
+                post = np.exp((cz[:, -1] - below) / (n - t))
+                flat = sse0 <= _TINY
+                confidence = np.where(flat, 0.0, np.clip(
+                    1.0 - sse[i, t - 1] / np.where(flat, 1.0, sse0),
+                    0.0, 1.0))
+                for sid, *got in zip(sids, t.tolist(), pre.tolist(),
+                                     post.tolist(), confidence.tolist()):
+                    seen, base, _ = rings[sid]
+                    out[sid] = _Cut(seen, base, *got)
+        return out
+
+    def _launch(self, z: np.ndarray, rows: int):
+        """Each row's cut (1-indexed prefix length) on a device backend, not
+        yet on the host: a jax array of ``rows`` rows (``z`` padded with
+        flat rows) that the device may still be computing.  ``None`` on
+        numpy, whose cut is the argmin of the f64 landscape the gates
+        read."""
+        if self.method == "numpy":
+            return None
+        y = np.zeros((rows, z.shape[1]), np.float32)
+        y[:len(z)] = z
+        if self.method == "jax":
+            from ..core.changepoint import estimate_changepoint_rows
+            return estimate_changepoint_rows(y, omega=self.omega)
+        from ..kernels.changepoint.ops import auto_block, changepoint_pallas_rows
+        return changepoint_pallas_rows(y, omega=self.omega,
+                                       block=auto_block(z.shape[1]))
+
     def _scan(self, stream_id: Hashable, tenant: str,
               st: _StreamState) -> Tuple[RegimeShift, ...]:
-        m = len(st.ring)
-        if m < self.min_points:
+        if len(st.ring) < self.min_points:
             return ()
         self._settle(st)
-        # Log vets: a regime shift multiplies the overhead channel, so it
-        # is additive here, and a single Pareto-tail spike no longer
-        # dominates the SSE.  Levels are reported back as geometric means.
-        z = np.log(np.maximum(np.asarray(st.ring, np.float64), _TINY))
-        with _span(self.tracer, "anomaly.launch", tid=self.trace_tid):
-            t = self._launch(z)
-        with _span(self.tracer, "anomaly.wait", tid=self.trace_tid):
-            t = int(t)  # 1-indexed prefix length within the ring
-        pre = float(np.exp(z[:t].mean()))
-        post = float(np.exp(z[t:].mean()))
-        sse0 = _single_segment_sse_f64(z)
-        sse2 = float(_closed_form_scan_f64(z, self.omega)[t - 1])
-        confidence = 0.0 if sse0 <= _TINY else \
-            float(np.clip(1.0 - sse2 / sse0, 0.0, 1.0))
+        cut = self._prepared.pop(stream_id, None)
+        if cut is not None and (cut.seen, cut.base) == (st.seen, st.base):
+            self._batched_scans += 1
+        else:
+            self._single_scans += 1
+            cut = self._batch({stream_id: (st.seen, st.base,
+                                           np.asarray(st.ring))}, 1)[stream_id]
+        t, pre, post, confidence = cut.t, cut.pre, cut.post, cut.confidence
         ratio = max(post, pre) / max(min(post, pre), _TINY)
         if confidence < self.min_confidence or ratio < self.min_ratio:
             st.candidate, st.hits = None, 0
@@ -366,24 +506,11 @@ class AnomalyMonitor:
         return (RegimeShift(stream_id=stream_id, tenant=tenant, onset=onset,
                             pre=pre, post=post, confidence=confidence),)
 
-    def _launch(self, y: np.ndarray):
-        """The backend's argmin (1-indexed prefix length), not yet on the
-        host: a numpy scalar, or a jax array the device may still be
-        computing."""
-        if self.method == "numpy":
-            return np.argmin(_closed_form_scan_f64(y, self.omega)) + 1
-        if self.method == "jax":
-            from ..core.changepoint import estimate_changepoint
-            return estimate_changepoint(np.asarray(y, np.float32),
-                                        omega=self.omega)
-        from ..kernels.changepoint.ops import auto_block, changepoint_pallas
-        return changepoint_pallas(np.asarray(y, np.float32),
-                                  omega=self.omega, block=auto_block(y.size))
-
     # ------------------------------------------------------------- churn
     def forget(self, stream_id: Hashable) -> None:
         """Drop a deregistered stream's state (its raised count survives)."""
         self._streams.pop(stream_id, None)
+        self._prepared.pop(stream_id, None)
 
     # ---------------------------------------------------- checkpointing
     def state_dict(self) -> dict:
@@ -392,6 +519,8 @@ class AnomalyMonitor:
             "method": self.method,
             "raised": self._raised,
             "underscanned": self._underscanned,
+            "batched_scans": self._batched_scans,
+            "single_scans": self._single_scans,
             "streams": [
                 {"sid": sid, "ring": list(st.ring), "base": st.base,
                  "seen": st.seen, "onsets": list(st.onsets),
@@ -407,6 +536,9 @@ class AnomalyMonitor:
         invariant, same as the mux's committed-window watermark)."""
         self._raised = int(state["raised"])
         self._underscanned = int(state.get("underscanned", 0))
+        self._batched_scans = int(state.get("batched_scans", 0))
+        self._single_scans = int(state.get("single_scans", 0))
+        self._prepared = {}
         self._streams = {}
         for rec in state["streams"]:
             st = _StreamState(self.confirm)

@@ -58,7 +58,9 @@ class MuxStats(NamedTuple):
     driver reports non-zero values.  ``anomalies`` counts regime-shift
     flags raised by the anomaly monitor (0 when monitoring is off);
     ``pressure_ticks`` the whole-mux ticks ``feed`` took because a
-    stream's ring was full (each also counts in ``ticks``).
+    stream's ring was full (each also counts in ``ticks``);
+    ``batched_scans``/``single_scans`` the monitor's scans whose cut came
+    from its per-tick batch, and those it launched alone.
     """
 
     ticks: int  # mux ticks
@@ -71,6 +73,8 @@ class MuxStats(NamedTuple):
     respawns: int = 0  # shard worker processes restarted after a crash
     anomalies: int = 0  # regime-shift flags raised (repro.fleet.anomaly)
     pressure_ticks: int = 0  # ticks ``feed`` took under ring pressure
+    batched_scans: int = 0  # monitor scans cut in the tick's batch
+    single_scans: int = 0  # monitor scans launched alone
 
 
 def _flush_loop(tick_fn, max_ticks: int):
@@ -306,12 +310,16 @@ class VetMux:
 
     @property
     def stats(self) -> MuxStats:
+        mon = self.monitor
         return MuxStats(ticks=self._ticks, dispatches=self._dispatches,
                         rows=self._rows, padded_rows=self._padded_rows,
                         deferred=self._deferred, streams=len(self._members),
-                        anomalies=(self.monitor.raised
-                                   if self.monitor is not None else 0),
-                        pressure_ticks=self._pressure_ticks)
+                        anomalies=mon.raised if mon is not None else 0,
+                        pressure_ticks=self._pressure_ticks,
+                        batched_scans=(mon.batched_scans
+                                       if mon is not None else 0),
+                        single_scans=(mon.single_scans
+                                      if mon is not None else 0))
 
     # ------------------------------------------------------------- ingest
     def feed(self, stream_id: Hashable, times) -> int:
@@ -491,16 +499,21 @@ class VetMux:
                     elif left > 0:
                         m.staleness += 1
             if self.monitor is not None:
-                # Same observe order as the collect loop (registration
-                # order), so flags are identical to the pre-split single
-                # loop — only the span boundary separates the phases.
+                # One batched scan of every stream's ring, then the
+                # per-stream gates in registration order, the order of the
+                # flags.  A stream with no window yet is offered too
+                # (``observe`` takes ``None`` as nothing): the batch's
+                # padded shape follows the fleet, not the streams that
+                # have windows.
                 with _span(self.tracer, "mux.anomaly", tid=self.trace_tid):
-                    for sid, m in self._members.items():
-                        if results[sid] is not None:
-                            flags.extend(self.monitor.observe(
-                                sid, results[sid].vet,
-                                first=m.stream.first_retained,
-                                tenant=m.tenant))
+                    entries = [(sid, getattr(results[sid], "vet", None),
+                                m.stream.first_retained)
+                               for sid, m in self._members.items()]
+                    self.monitor.prepare(entries)
+                    for (sid, vets, first), m in zip(
+                            entries, self._members.values()):
+                        flags.extend(self.monitor.observe(
+                            sid, vets, first=first, tenant=m.tenant))
             tick_span.set(dispatches=dispatches, rows=rows)
 
         self._dispatches += dispatches

@@ -526,7 +526,9 @@ class ShardedVetMux:
                         deferred=sum(s.deferred for s in per),
                         streams=len(self._placed),
                         anomalies=sum(s.anomalies for s in per),
-                        pressure_ticks=sum(s.pressure_ticks for s in per))
+                        pressure_ticks=sum(s.pressure_ticks for s in per),
+                        batched_scans=sum(s.batched_scans for s in per),
+                        single_scans=sum(s.single_scans for s in per))
 
     @property
     def shard_stats(self) -> Tuple[MuxStats, ...]:

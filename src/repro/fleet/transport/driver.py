@@ -780,7 +780,9 @@ class TransportVetMux:
                         retries=sum(h.retries for h in self._handles),
                         respawns=sum(h.respawns for h in self._handles),
                         anomalies=sum(s.anomalies for s in per),
-                        pressure_ticks=sum(s.pressure_ticks for s in per))
+                        pressure_ticks=sum(s.pressure_ticks for s in per),
+                        batched_scans=sum(s.batched_scans for s in per),
+                        single_scans=sum(s.single_scans for s in per))
 
     @property
     def shard_stats(self) -> Tuple[MuxStats, ...]:

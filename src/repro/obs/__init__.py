@@ -10,9 +10,9 @@ itself.  Three pieces, zero dependencies:
   ``TickReply`` for cross-process reassembly (``Tracer.adopt``).  Every
   layer — mux plan/coalesce/dispatch/commit/collect/anomaly, the
   engine's dispatch and the fused launch's ``vet.stage/launch/wait/
-  fetch`` inside it, the anomaly monitor's ``anomaly.scan`` with its
-  ``anomaly.launch``/``anomaly.wait``, shard fan-out, transport round
-  trips — times itself through this one seam.  Streams record no spans
+  fetch`` inside it, the anomaly monitor's ``anomaly.batch`` with its
+  ``anomaly.launch``/``anomaly.wait`` and ``anomaly.scan``, shard
+  fan-out, transport round trips — times itself through this one seam.  Streams record no spans
   of their own (one a stream a tick would swamp every other).
   ``Tracer(annotate=True)`` also writes each span into the
   ``jax.profiler`` trace as a ``TraceAnnotation`` of its name, on the
@@ -28,7 +28,7 @@ itself.  Three pieces, zero dependencies:
   PRs are judged by.
 
 Wiring: ``VetMux(..., tracer=t)`` / ``mux.set_tracer(t)`` threads the
-tracer down to its engine (the anomaly monitor, three spans a scanned
+tracer down to its engine (the anomaly monitor, a span a scanned
 stream, is attached on its own: ``mux.monitor.set_tracer(t)``);
 ``ShardedVetMux.set_tracer`` gives each shard mux its own ``tid`` lane;
 ``TransportVetMux(..., tracer=t)`` enables worker-side tracers over the

@@ -309,3 +309,173 @@ class TestMonitorRidesMuxCheckpoint:
         fresh.register("w0", window=8, stride=8, capacity=64)
         fresh.load_state_dict(state)  # must not raise
         assert fresh.stats.anomalies == 0
+
+
+# ------------------------------------------------------ batched scans
+METHODS = ["numpy", "jax", "pallas"]  # pallas interprets off the chip
+
+
+def _rings(n, rows=13, seed=0):
+    """Log-vet-like rings of length ``n``; every third carries a planted
+    step."""
+    rng = np.random.default_rng(seed + n)
+    v = rng.lognormal(0.2, 0.3, (rows, n))
+    v[::3, n // 2:] *= 4.0
+    return v
+
+
+def _single_cut(method, z, omega):
+    """The single-stream launch each method made before scans were
+    batched: one ring, one call."""
+    if method == "numpy":
+        from repro.fleet.anomaly import _closed_form_scan_f64
+        return int(np.argmin(_closed_form_scan_f64(z, omega))) + 1
+    y = np.asarray(z, np.float32)
+    if method == "jax":
+        from repro.core.changepoint import estimate_changepoint
+        return int(estimate_changepoint(y, omega=omega))
+    from repro.kernels.changepoint.ops import auto_block, changepoint_pallas
+    return int(changepoint_pallas(y, omega=omega, block=auto_block(z.size)))
+
+
+class TestBatchedScan:
+    @pytest.mark.parametrize("n", [6, 17, 64])
+    @pytest.mark.parametrize("method", METHODS)
+    def test_batched_cut_is_the_single_stream_cut(self, method, n):
+        mon = AnomalyMonitor(method)
+        v = _rings(n)
+        cuts = mon._batch({k: (n, 0, row) for k, row in enumerate(v)}, 16)
+        got = [cuts[k].t for k in range(len(v))]
+        want = [_single_cut(method, np.log(row), mon.omega) for row in v]
+        assert got == want
+
+    @pytest.mark.parametrize("n", [6, 17, 64])
+    @pytest.mark.parametrize("method", METHODS)
+    def test_levels_and_confidence_match_the_per_stream_formulas(
+            self, method, n):
+        from repro.fleet.anomaly import _closed_form_scan_f64
+        mon = AnomalyMonitor(method)
+        v = _rings(n)
+        cuts = mon._batch({k: (n, 0, row) for k, row in enumerate(v)}, 16)
+        for k, row in enumerate(v):
+            z = np.log(row)
+            t = _single_cut(method, z, mon.omega)
+            # The one-ring formulas the monitor applied before batching.
+            idx = np.arange(1, n + 1, dtype=np.float64)
+            sx, sxx = idx.sum(), (idx * idx).sum()
+            sxx_c = sxx - sx * sx / n
+            syy_c = (z * z).sum() - z.sum() ** 2 / n
+            sxy_c = (idx * z).sum() - sx * z.sum() / n
+            sse0 = max(syy_c - sxy_c * sxy_c / sxx_c, 0.0)
+            sse2 = _closed_form_scan_f64(z, mon.omega)[t - 1]
+            got = cuts[k]
+            assert (got.seen, got.base, got.t) == (n, 0, t)
+            assert got.pre == pytest.approx(np.exp(z[:t].mean()), rel=1e-12)
+            assert got.post == pytest.approx(np.exp(z[t:].mean()), rel=1e-12)
+            assert got.confidence == pytest.approx(
+                np.clip(1.0 - sse2 / sse0, 0.0, 1.0), rel=1e-12, abs=1e-15)
+
+    @pytest.mark.parametrize("name", ["degraded_node", "contention_onset"])
+    @pytest.mark.parametrize("method", METHODS)
+    def test_mux_flags_match_lone_observes(self, method, name):
+        """A mux that batches each tick's scans raises the flags a mux
+        whose monitor scans each stream alone raises."""
+        def flags(batched):
+            mon = AnomalyMonitor(method)
+            if not batched:
+                mon.prepare = lambda entries: None
+            mux = VetMux(VetEngine("numpy", buckets=64), monitor=mon)
+            ticks = play(build(name, seed=SEED), mux)
+            stats = mux.stats
+            assert (stats.batched_scans > 0, stats.single_scans > 0) == \
+                (batched, not batched)
+            return [(f.stream_id, f.onset) for t in ticks for f in t.flags]
+        got = flags(batched=True)
+        assert got and got == flags(batched=False)
+
+    def test_prepare_changes_no_state(self):
+        mon = AnomalyMonitor("numpy", min_points=8)
+        v = _rings(20, rows=3)
+        for k, row in enumerate(v):
+            mon.observe(k, row[:12], first=0)
+        before = mon.state_dict()
+        mon.prepare([(k, row, 0) for k, row in enumerate(v)]
+                    + [("new", v[0], 0), ("short", v[0][:4], 0)])
+        assert mon.state_dict() == before
+        assert set(mon._prepared) == {0, 1, 2, "new"}
+
+
+class _Counted:
+    """A backend stand-in: counts calls and the shapes they see, and the
+    fetches of what they return."""
+
+    def __init__(self, real):
+        self.real, self.shapes, self.fetches = real, [], 0
+
+    def __call__(self, y, **kw):
+        self.shapes.append(np.shape(y))
+        out = self.real(y, **kw)
+        counted = self
+
+        class Fetch:
+            def __array__(self, dtype=None, copy=None):
+                counted.fetches += 1
+                return np.asarray(out)
+        return Fetch()
+
+
+@pytest.mark.parametrize("method,module,name", [
+    ("jax", "repro.core.changepoint", "estimate_changepoint_rows"),
+    ("pallas", "repro.kernels.changepoint.ops", "changepoint_pallas_rows"),
+])
+def test_one_launch_and_fetch_a_ring_length_a_tick(method, module, name,
+                                                   monkeypatch):
+    """However many streams a tick brings new windows (1, 3, all 5), the
+    monitor makes one backend call and one fetch for each ring length, at
+    one padded row count (8, the power of two above the 5 streams)."""
+    import importlib
+
+    from repro.obs import Tracer
+    stub = _Counted(getattr(importlib.import_module(module), name))
+    monkeypatch.setattr(importlib.import_module(module), name, stub)
+    tr = Tracer()
+    mon = AnomalyMonitor(method, ring=8)
+    mon.set_tracer(tr)
+    mux = VetMux(VetEngine("numpy", buckets=16), monitor=mon)
+    rng = np.random.default_rng(3)
+    sids = [f"w{k}" for k in range(5)]
+    for sid in sids:
+        mux.register(sid, window=8, stride=8, capacity=256)
+        mux.feed(sid, rng.lognormal(0.0, 0.3, 8 * 10))  # 10 windows: full
+    mux.tick()
+    assert stub.shapes == [(8, 8)] and stub.fetches == 1
+    for fed in (sids[:1], sids[:3], sids):
+        stub.shapes, stub.fetches = [], 0
+        for sid in fed:
+            mux.feed(sid, rng.lognormal(0.0, 0.3, 8))
+        mux.tick()
+        assert stub.shapes == [(8, 8)] and stub.fetches == 1, fed
+    # A stream that joins late has a shorter ring: its own group.
+    mux.register("late", window=8, stride=8, capacity=256)
+    mux.feed("late", rng.lognormal(0.0, 0.3, 8 * 7))
+    for sid in sids:
+        mux.feed(sid, rng.lognormal(0.0, 0.3, 8))
+    stub.shapes, stub.fetches = [], 0
+    mux.tick()
+    assert sorted(stub.shapes) == [(8, 7), (8, 8)] and stub.fetches == 2
+    # Every scan's cut came from the tick's batch.
+    scans = sum(r.name == "anomaly.scan" for r in tr.records)
+    assert scans == 5 + 1 + 3 + 5 + 6
+    stats = mux.stats
+    assert (stats.batched_scans, stats.single_scans) == (scans, 0)
+    # A lone observe launches alone, with one row.
+    stub.shapes = []
+    mon.observe("lone", rng.lognormal(0.0, 0.3, 8), first=0)
+    assert stub.shapes == [(1, 8)] and mon.single_scans == 1
+    # The counters ride the checkpoint.
+    twin = VetMux(VetEngine("numpy", buckets=16),
+                  monitor=AnomalyMonitor(method, ring=8))
+    for sid in sids + ["late"]:
+        twin.register(sid, window=8, stride=8, capacity=256)
+    twin.load_state_dict(mux.state_dict())
+    assert (twin.stats.batched_scans, twin.stats.single_scans) == (scans, 1)

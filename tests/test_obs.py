@@ -424,8 +424,8 @@ def test_mux_tick_span_tree():
     by_name, sid_name = _by_name(tr.records)
     assert {"mux.tick", "mux.plan", "mux.coalesce", "mux.dispatch",
             "mux.commit", "mux.collect", "mux.anomaly",
-            "engine.dispatch", "anomaly.scan", "anomaly.launch",
-            "anomaly.wait"} <= set(by_name)
+            "engine.dispatch", "anomaly.scan", "anomaly.batch",
+            "anomaly.launch", "anomaly.wait"} <= set(by_name)
     # Streams record no spans of their own: one a stream a tick would
     # outnumber every other span at fleet sizes.
     assert not any(n.startswith("stream.") for n in by_name)
@@ -439,14 +439,16 @@ def test_mux_tick_span_tree():
         assert sid_name[r.parent] == "mux.dispatch"
         attrs = dict(r.attrs)
         assert attrs["bytes"] > 0 and attrs["backend"] == "numpy"
-    # One scan a stream, each with its launch and its wait inside.
+    # One scan a stream; the streams' rings (one length) scanned in one
+    # batch, with one launch and one wait of all 8 rows inside.
     assert len(by_name["anomaly.scan"]) == 8
     for r in by_name["anomaly.scan"]:
         assert sid_name[r.parent] == "mux.anomaly"
+    (batch,) = by_name["anomaly.batch"]
+    assert sid_name[batch.parent] == "mux.anomaly"
     for name in ("anomaly.launch", "anomaly.wait"):
-        assert len(by_name[name]) == 8
-        for r in by_name[name]:
-            assert sid_name[r.parent] == "anomaly.scan", name
+        (r,) = by_name[name]
+        assert r.parent == batch.sid and dict(r.attrs)["rows"] == 8, name
     # The whole tree exports and nests cleanly.
     assert validate_chrome(to_chrome(tr.records)) == []
 
@@ -464,10 +466,13 @@ def test_monitor_and_fused_launch_span_trees(backend):
     _feed_all(mux, n=4, chunk=40)
     mux.tick()
     by_name, sid_name = _by_name(tr.records)
-    assert len(by_name["anomaly.scan"]) == 4
+    assert [sid_name[r.parent] for r in by_name["anomaly.scan"]] == \
+        ["mux.anomaly"] * 4
+    assert [sid_name[r.parent] for r in by_name["anomaly.batch"]] == \
+        ["mux.anomaly"]
     for name in ("anomaly.launch", "anomaly.wait"):
-        assert [sid_name[r.parent] for r in by_name[name]] == \
-            ["anomaly.scan"] * 4
+        assert [(sid_name[r.parent], dict(r.attrs)["rows"])
+                for r in by_name[name]] == [("anomaly.batch", 4)]
     vet = [n for n in by_name if n.startswith("vet.")]
     if backend == "numpy":
         assert vet == []

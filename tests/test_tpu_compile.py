@@ -17,7 +17,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.engine import VetEngine
-from repro.kernels.changepoint.ops import auto_block, changepoint_pallas
+from repro.kernels.changepoint.ops import (auto_block, changepoint_pallas,
+                                           changepoint_pallas_rows)
 from repro.kernels.windowvet.kernel import fused_window_vet_scan
 
 FLEET_ROWS = 16384  # windows in one tick of a 16k-stream fleet
@@ -69,8 +70,29 @@ def test_changepoint_scan_compiles(one_chip, n):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("rows,n", [(1, 64), (1024, 64), (16384, 64),
+                                    (64, 1000)])
+def test_batched_changepoint_scan_compiles(one_chip, rows, n):
+    """The monitor's scan of every stream's ring in one launch (rows
+    padded to a power of two; a 64-window ring; one long ring)."""
+    compiled = changepoint_pallas_rows.lower(
+        _spec((rows, n), jnp.float32, one_chip), omega=3,
+        block=auto_block(n), interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_jax_gather_batch_compiles(one_chip):
     batch = VetEngine("jax")._make_batch_fn()
     compiled = batch.lower(
         _spec((FLEET_ROWS, 256), jnp.float32, one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes > 0
+
+
+def test_pallas_gather_batch_compiles(one_chip):
+    """The pallas engine's gather path (the bucketed rows the fused kernel
+    does not serve) maps the one-series scan over rows: its one-row block
+    stays lowerable once the map adds a row axis."""
+    batch = VetEngine("pallas", interpret=False)._make_batch_fn()
+    compiled = batch.lower(
+        _spec((FLEET_ROWS, 256), jnp.float32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
