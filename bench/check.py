@@ -249,7 +249,8 @@ def _flags_unmatched(cfg, drv, rows, logs, control) -> int:
     """Flags raised by the program (or the control's monitor) and by the
     reference monitor over the same committed vets, tick by tick (the
     ticks ``mux.feed`` took under ring pressure included), from the first
-    tick of the run on; the count of flags in only one of them."""
+    tick of the run on; the count of flags in only one of them.  Each tick
+    is one ``RefMonitor.observe_tick`` over every stream it moved."""
     settings = cfg["monitor"]  # the program's monitor, as configured
     ref = R.RefMonitor(**settings)
     shadow = (R.RefMonitor(**settings, dtype=control_dtype())
@@ -266,13 +267,10 @@ def _flags_unmatched(cfg, drv, rows, logs, control) -> int:
     for before, after, flags in steps:
         if shadow is None:
             got.update(flags)
-        for s in np.flatnonzero(after > before):
-            new = vets[s][before[s]:after[s]]
-            f = ref.observe(int(s), new, int(before[s]))
-            if f is not None:
-                want.add((int(s), f[0]))
-            if shadow is not None:
-                g = shadow.observe(int(s), new, int(before[s]))
-                if g is not None:
-                    got.add((int(s), g[0]))
+        arrivals = [(int(s), vets[s][before[s]:after[s]], int(before[s]))
+                    for s in np.flatnonzero(after > before)]
+        want.update((s, f[0]) for s, f in ref.observe_tick(arrivals).items())
+        if shadow is not None:
+            got.update((s, f[0])
+                       for s, f in shadow.observe_tick(arrivals).items())
     return len(want ^ got)

@@ -449,6 +449,11 @@ class Live:
                             for s in range(self.fleet.streams)})
         return {s: x.sum(axis=1) for s, x in got.items()}
 
+    def offered(self, start: float, end: float) -> int:
+        """Records due from ``start`` to ``end`` seconds into the window."""
+        return int((T.fed_by(self.sched, end)
+                    - T.fed_by(self.sched, start)).sum())
+
     def last_due(self, s: int, j: np.ndarray) -> np.ndarray:
         """When the last record of window ``j`` of stream ``s`` was due."""
         w, st = int(self.fleet.windows[s]), int(self.fleet.strides[s])
@@ -498,6 +503,40 @@ def monitor_scans(logs, vetted0: np.ndarray, ring: int) -> np.ndarray:
         out.append(np.searchsorted(marks, js + ring, side="right")
                    - np.searchsorted(marks, js, side="right"))
     return np.concatenate(out) if out else np.zeros(0, np.int64)
+
+
+class GcLog:
+    """Every pass of Python's collector while it is entered, as
+    ``(generation, start, seconds)`` on ``perf_counter``, through
+    ``gc.callbacks``.  It is kept apart from the mux's tracer, so no span's
+    self time changes."""
+
+    def __init__(self):
+        self.passes: List[tuple] = []
+        self._start: Optional[float] = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._start = time.perf_counter()
+        elif self._start is not None:
+            self.passes.append((info["generation"], self._start,
+                                time.perf_counter() - self._start))
+            self._start = None
+
+    def __enter__(self) -> "GcLog":
+        gc.callbacks.append(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        gc.callbacks.remove(self)
+
+    def line(self) -> str:
+        full = [d for g, _, d in self.passes if g == 2]
+        return (f"[bench] collector in the window: {len(self.passes)} "
+                f"passes, {1e3 * sum(d for _, _, d in self.passes):.1f} ms; "
+                f"generation 2: {len(full)} passes, "
+                f"{1e3 * sum(full):.1f} ms, longest "
+                f"{1e3 * max(full, default=0.0):.1f} ms")
 
 
 def _memo_hits(mux) -> int:
@@ -575,7 +614,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool = False,
     t0 = time.perf_counter()
     drv.t0 = t0
     setup_s = t0 - t_start
-    with note("bench.window"):
+    with note("bench.window"), GcLog() as gc_log:
         while True:
             feed_s = time.perf_counter() - t0
             rec.feeding = True
@@ -624,6 +663,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool = False,
         "{:.1f} / {:.1f} ms (10th, 50th, 90th percentile)".format(
             1e3 * np.mean(gens), *(1e3 * np.quantile(timed, [.1, .5, .9]))),
     ]
+    lines.append(gc_log.line())
     half = window[len(window) // 2 - 1] if len(window) > 1 else None
     if half is not None:
         lines.append("[bench] records_per_s over the first and the second "
@@ -632,12 +672,20 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool = False,
                          / half.end_s,
                          covered_records(fleet, half.vetted, vetted1)
                          / (window_s - half.end_s)))
+        if isinstance(drv, Live):
+            lines.append("[bench] offered over the same halves: {:.1f} / "
+                         "{:.1f} records/s".format(
+                             drv.offered(0.0, half.end_s) / half.end_s,
+                             drv.offered(half.end_s, seconds)
+                             / (window_s - half.end_s)))
+    new = np.diff(np.stack([vetted0] + [lg.vetted for lg in window]), axis=0)
+    per_tick = new.sum(axis=1)
+    lines.append(f"[bench] windows a tick: median {np.median(per_tick):.0f}, "
+                 f"max {int(per_tick.max())}")
     mon = cfg.get("monitor")
     if mon:
         ring, confirm = int(mon["ring"]), int(mon["confirm"])
         scans = monitor_scans(logs, vetted0, ring)
-        new = np.diff(np.stack([vetted0] + [lg.vetted for lg in window]),
-                      axis=0)
         lines.append(
             f"[bench] monitor: {int((scans < confirm).sum())} of "
             f"{scans.size} windows scanned fewer than {confirm} times "
@@ -646,6 +694,8 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool = False,
     values = {"setup_s": setup_s,
               "records_per_s": covered / window_s}
     if isinstance(drv, Live):
+        lines.append(f"[bench] offered: "
+                     f"{drv.offered(0.0, seconds) / seconds:.1f} records/s")
         lat = window_latencies(window, vetted0, drv.last_due)
         if lat.size:
             values["window_latency_p50_ms"] = 1e3 * float(np.quantile(lat, .5))
@@ -678,7 +728,8 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool = False,
         device["window_s"] = red.window_s
         breakdown = red.breakdown()
         ctx = tracing.Context(red, tracer.records, loop, vetted0, fleet,
-                              peaks, t0, t_end)
+                              peaks, t0, t_end, gc_passes=gc_log.passes,
+                              host=values)
         for m in cell.per_layer:
             v = load_reader(m["name"], cell.root)(ctx)
             if v is not None:

@@ -16,7 +16,9 @@ It imports nothing of the program.  For each window of raw record times:
 
 ``RefMonitor`` is the regime-shift monitor's gates (``omega``, ``ring``,
 ``min_confidence``, ``min_ratio``, ``confirm``) on the same float64 scan,
-run over log window vets one level up.
+run over log window vets one level up.  ``observe`` scans one stream's
+ring; ``observe_tick`` scans every ring a tick moved, one ``landscape`` a
+ring length, and gives the same numbers and flags.
 
 Everything is vectorised over a block of windows of one length, so that a
 whole tick of a fleet is a few numpy passes.
@@ -171,10 +173,9 @@ class RefMonitor:
         self.confirm = max(int(confirm), 1)
         self._rings: Dict[Hashable, _Ring] = {}
 
-    def observe(self, sid: Hashable, new_vets, first: int):
-        """Windows ``first, first + 1, ...`` of stream ``sid`` arrived in
-        one tick with vets ``new_vets``; returns ``(onset, pre, post)`` of
-        a raised flag, or ``None``."""
+    def _push(self, sid: Hashable, new_vets, first: int) -> Optional[_Ring]:
+        """Add windows ``first, first + 1, ...`` of stream ``sid`` to its
+        ring; the ring, or ``None`` where none arrived."""
         st = self._rings.setdefault(sid, _Ring())
         if first != st.seen:
             raise ValueError(f"stream {sid!r}: windows from {first}, "
@@ -190,7 +191,62 @@ class RefMonitor:
         if drop > 0:
             del st.vets[:drop]
             st.base += drop
-        return self._scan(st)
+        return st
+
+    def observe(self, sid: Hashable, new_vets, first: int):
+        """Windows ``first, first + 1, ...`` of stream ``sid`` arrived in
+        one tick with vets ``new_vets``; returns ``(onset, pre, post)`` of
+        a raised flag, or ``None``."""
+        st = self._push(sid, new_vets, first)
+        return None if st is None else self._scan(st)
+
+    def observe_tick(self, arrivals) -> Dict[Hashable, tuple]:
+        """One tick's ``(sid, new_vets, first)`` of every stream it brought
+        windows, each as ``observe`` takes them; returns ``{sid: (onset,
+        pre, post)}`` of the flags raised.  The rings of one length are
+        scanned as the rows of one block; each stream's gates and
+        confirmation are ``observe``'s."""
+        groups: Dict[int, List[Tuple[Hashable, _Ring]]] = {}
+        for sid, new_vets, first in arrivals:
+            st = self._push(sid, new_vets, first)
+            if st is not None and len(st.vets) >= self.min_points:
+                groups.setdefault(len(st.vets), []).append((sid, st))
+        out = {}
+        for m, group in groups.items():
+            z = np.log(np.maximum(np.array([st.vets for _, st in group]),
+                                  TINY))
+            for (sid, st), g in zip(group, zip(*self._gates(z))):
+                f = self._decide(st, *g)
+                if f is not None:
+                    out[sid] = f
+        return out
+
+    def _gates(self, z: np.ndarray):
+        """``(t, pre, post, conf, ratio)`` of rings ``z`` (rows, m) of log
+        vets, each of shape (rows,), as ``_scan`` computes them for a row:
+        the reductions run along each row, and the means before and after
+        the cut over rows that share their cut."""
+        rows, m = z.shape
+        sse, _ = landscape(z, self.omega)
+        cut = sse if np.dtype(self.dtype) == np.float64 else landscape(
+            z, self.omega, self.dtype)[0]
+        t = np.argmin(cut, axis=-1) + 1
+        pre, post = np.empty(rows), np.empty(rows)
+        for c in np.unique(t):
+            i = np.flatnonzero(t == c)
+            pre[i] = np.exp(z[i, :c].mean(axis=-1))
+            post[i] = np.exp(z[i, c:].mean(axis=-1))
+        k = np.arange(1, m + 1, dtype=np.float64)
+        one = _segment_sse(float(m), k.sum(), z.sum(axis=-1), (k * k).sum(),
+                           (k * z).sum(axis=-1), (z * z).sum(axis=-1))
+        best = sse[np.arange(rows), t - 1]
+        safe = np.where(one <= TINY, 1.0, one)
+        conf = np.where(one <= TINY, 0.0,
+                        np.minimum(np.maximum(1.0 - best / safe, 0.0), 1.0))
+        ratio = np.maximum(pre, post) / np.maximum(np.minimum(pre, post),
+                                                   TINY)
+        return t.tolist(), pre.tolist(), post.tolist(), conf.tolist(), \
+            ratio.tolist()
 
     def _scan(self, st: _Ring):
         m = len(st.vets)
@@ -209,6 +265,11 @@ class RefMonitor:
         conf = 0.0 if one <= TINY else min(max(1.0 - sse[0, t - 1] / one,
                                                0.0), 1.0)
         ratio = max(pre, post) / max(min(pre, post), TINY)
+        return self._decide(st, t, pre, post, conf, ratio)
+
+    def _decide(self, st: _Ring, t: int, pre: float, post: float,
+                conf: float, ratio: float):
+        """The gates and the confirmation of one scan of ``st``."""
         if conf < self.min_confidence or ratio < self.min_ratio:
             st.candidate, st.hits = None, 0
             return None

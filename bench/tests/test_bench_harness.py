@@ -58,7 +58,7 @@ def test_sound_run_is_correct(kind, sound):
     assert sound.attempted > 0 and sound.failed == 0
     names = {"setup_s", "records_per_s"}
     if kind == "live":
-        names |= {"window_latency_p50_ms", "window_latency_p99_ms"}
+        names |= {"window_latency_p50_ms"}
         assert sound.checks["flags_unmatched"]["value"] == 0
     assert set(sound.metrics) == names
     assert all(m["value"] > 0 for m in sound.metrics.values())

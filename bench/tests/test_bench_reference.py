@@ -72,3 +72,100 @@ def test_ref_monitor_raises_what_the_program_monitor_raises():
                 want.append((s, r[0]))
             seen = upto
     assert got == want and len(want) == 3
+
+
+def _dtypes():
+    from bench.check import control_dtype
+    return [np.float64, control_dtype()]
+
+
+@pytest.mark.parametrize("dtype", _dtypes(), ids=["float64", "bfloat16"])
+def test_batched_monitor_scans_as_the_per_stream_one(dtype):
+    """``observe_tick`` raises the flags ``observe`` raises, with the same
+    onsets, ``pre`` and ``post``, bit for bit: rings of many lengths as
+    they fill, streams that bring several windows a tick or none, and a
+    level shift in a third of them."""
+    settings = dict(ring=24, omega=3, min_points=0, min_confidence=0.25,
+                    min_ratio=2.0, confirm=3)
+    one = R.RefMonitor(**settings, dtype=dtype)
+    batch = R.RefMonitor(**settings, dtype=dtype)
+    rng = np.random.default_rng(8)
+    n = 40
+    seen = np.zeros(n, np.int64)
+    level = np.ones(n)
+    raised = 0
+    for tick in range(120):
+        if tick == 40:
+            level[rng.random(n) < 0.35] = 6.0
+        k = rng.integers(0, 4, n)
+        k[rng.random(n) < 0.4] = 0
+        arrivals = []
+        for s in np.flatnonzero(k):
+            vets = 1.5 * level[s] * np.exp(rng.normal(0, 0.2, k[s]))
+            arrivals.append((int(s), vets, int(seen[s])))
+            seen[s] += k[s]
+        want = {}
+        for s, vets, first in arrivals:
+            f = one.observe(s, vets, first)
+            if f is not None:
+                want[s] = f
+        assert batch.observe_tick(arrivals) == want, tick
+        raised += len(want)
+    assert raised >= 5
+
+
+LIVE_SEEDS = [2 ** 31 + 41, 2 ** 33 + 7]
+
+
+@pytest.fixture(scope="module", params=LIVE_SEEDS)
+def live_vets(request):
+    """Each tick's new committed vets of a seeded live run at a tiny size
+    (numpy backend): ``[(sid, vets, first), ...]`` a tick."""
+    from bench import check, harness
+    cell = harness.load_cell("mon1k.live")
+    cell = cell._replace(
+        config={**cell.config, "backend": "numpy", "streams": 16,
+                "windows": [16], "capacity_records": 512},
+        traffic={**cell.traffic, "pool_records": 1 << 14, "pace": 8.0,
+                 "history_windows": 8,
+                 "shift": {"kind": "degraded_node", "fraction": 0.5,
+                           "boost": 16.0, "onset": 0.25}})
+    built = []
+
+    def build(cfg):
+        built.append(harness.build_mux(cfg))
+        return built[-1]
+    harness.run_cell(cell, seed=request.param, seconds=1.5, build=build)
+    (mux,) = built
+    logs = mux.tick.logs
+    top = logs[-1].vetted
+    rows = check.Rows(mux)
+    vets = {s: rows.get(s, np.arange(0, top[s]))[0]
+            for s in range(len(mux)) if top[s]}
+    ticks, prev = [], np.zeros(len(mux), np.int64)
+    for lg in logs:
+        ticks.append([(int(s), vets[s][prev[s]:lg.vetted[s]], int(prev[s]))
+                      for s in np.flatnonzero(lg.vetted > prev)])
+        prev = lg.vetted
+    return ticks
+
+
+@pytest.mark.parametrize("dtype", _dtypes(), ids=["float64", "bfloat16"])
+def test_batched_monitor_on_live_runs(live_vets, dtype):
+    """On the vets a seeded live run committed, tick by tick, the batched
+    reference gives the per-stream reference's flags, onsets, ``pre`` and
+    ``post`` exactly."""
+    settings = dict(ring=64, omega=3, min_points=0, min_confidence=0.25,
+                    min_ratio=2.0, confirm=3)
+    one = R.RefMonitor(**settings, dtype=dtype)
+    batch = R.RefMonitor(**settings, dtype=dtype)
+    raised = []
+    for arrivals in live_vets:
+        want = {}
+        for s, vets, first in arrivals:
+            f = one.observe(s, vets, first)
+            if f is not None:
+                want[s] = f
+        assert batch.observe_tick(arrivals) == want
+        raised += list(want.items())
+    assert raised

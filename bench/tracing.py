@@ -198,11 +198,19 @@ class Context:
     time per tick of the mux's ``name`` spans in the window (a span's
     duration less that of the spans directly inside it); ``vetted_lengths``
     the window length of every window the timed ticks vetted; ``peaks`` the
-    device's row of ``bench/peaks.json``."""
+    device's row of ``bench/peaks.json``; ``gc_ms()`` the collector's time
+    per tick in the window, from ``gc_passes`` (``(generation, start,
+    seconds)``, as ``harness.GcLog`` records them); ``host_value(name)`` a
+    number the harness took on the host's clock over the whole window, from
+    ``host`` (``{name: value}``), such as a latency quantile."""
 
     def __init__(self, reduced: Reduced, spans, logs, vetted0, fleet,
-                 peaks: Optional[dict], t0: float, t_end: float):
+                 peaks: Optional[dict], t0: float, t_end: float,
+                 gc_passes=None, host: Optional[dict] = None):
         self.trace = reduced
+        self.host = dict(host or {})
+        self.gc_s = None if gc_passes is None else sum(
+            d for _, s, d in gc_passes if s >= t0 and s + d <= t_end)
         self.ticks = len(logs)
         self.peaks = peaks
         inside = [r for r in spans if r.ts >= t0 and r.ts + r.dur <= t_end]
@@ -221,6 +229,14 @@ class Context:
         if not any(n in self._self for n in names):
             return None
         return 1e3 * sum(self._self.get(n, 0.0) for n in names) / self.ticks
+
+    def gc_ms(self) -> Optional[float]:
+        if self.gc_s is None:
+            return None
+        return 1e3 * self.gc_s / self.ticks
+
+    def host_value(self, name: str) -> Optional[float]:
+        return self.host.get(name)
 
     def idle_share(self) -> Optional[float]:
         if self.trace.window_s <= 0:
